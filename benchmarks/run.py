@@ -28,8 +28,10 @@ def runtime_overheads(rep) -> None:
 
 
 def kernel_microbench(rep) -> None:
-    """Interpret-mode Pallas vs jnp-chunked wall time at small shapes (CPU
-    correctness-path cost; TPU perf comes from the roofline analysis)."""
+    """Liveness rows: the flash kernel (interpret mode off the TPU), the
+    chunked jnp path and the reference each run at a small shape.  The wall
+    times only show the paths run; they are not a speed, on any backend."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -43,7 +45,7 @@ def kernel_microbench(rep) -> None:
     v = jnp.asarray(rng.normal(size=(B, S, K, D)), jnp.float32)
 
     for name, fn in [
-        ("flash_pallas_interp", lambda: flash_attention_pallas(q, k, v, causal=True)),
+        ("flash_pallas", lambda: flash_attention_pallas(q, k, v, causal=True)),
         ("flash_jnp_chunked", lambda: ops._attention_chunked_jnp(
             q, k, v, causal=True, window=None, logit_cap=None, q_offset=0,
             scale=D**-0.5, block_k=128)),
@@ -54,7 +56,11 @@ def kernel_microbench(rep) -> None:
         reps = 3
         for _ in range(reps):
             fn().block_until_ready()
-        rep.row(f"kernel/{name}", (time.perf_counter() - t0) / reps * 1e6)
+        rep.row(
+            f"liveness/kernel/{name}",
+            (time.perf_counter() - t0) / reps * 1e6,
+            platform=jax.default_backend(),
+        )
 
 
 def roofline_summary(rep) -> None:

@@ -68,21 +68,6 @@ def _engine_parts(max_batch: int, max_new: int):
     return cfg, params, scfg
 
 
-def _warm(engine, max_batch: int) -> None:
-    """Compile every shape the run will hit outside the measured window.
-
-    Prefill jits per (group_size, bucketed_len) and the slot insert per
-    group size, so a single warm request leaves ``max_batch - 1`` compiles
-    to land mid-window — each engine owns its own jit wrappers, which is
-    exactly the asymmetry that makes multi-engine rows look slow."""
-    for g in range(1, max_batch + 1):
-        engine.admit([(f"warm{g}-{j}", [1, 2, 3, 4, 5], 2) for j in range(g)])
-        while engine.n_live():
-            engine.step_chunk()
-    for k in engine.stats:
-        engine.stats[k] = 0
-
-
 def _open_loop_once(
     rep,
     *,
@@ -96,6 +81,8 @@ def _open_loop_once(
     seed: int = 0,
     e1_tokens_per_s: Optional[float] = None,
 ) -> float:
+    import jax
+
     from repro.serve import ContinuousEngine
     from repro.serve import request_plane as rp
 
@@ -110,9 +97,17 @@ def _open_loop_once(
     with tempfile.TemporaryDirectory() as workdir:
         store, kv, cleanup = _make_stores(backend, workdir)
         try:
-            engines = [ContinuousEngine(cfg, params, scfg) for _ in range(n_engines)]
+            # one process, one engine per device (round-robin when the
+            # engines outnumber the devices, as on a one-device CPU host)
+            devices = jax.devices()
+            engines = [
+                ContinuousEngine(cfg, params, scfg, device=devices[i % len(devices)])
+                for i in range(n_engines)
+            ]
             for e in engines:
-                _warm(e, max_batch)
+                # decode and the prompt bucket's prefill compile outside
+                # the measured window: each engine owns its own jit wrappers
+                e.warm()
             idle_s = max(2.5, 6.0 / offered_rps)
             threads = [
                 threading.Thread(
@@ -199,6 +194,8 @@ def main(argv=None) -> int:
     import argparse
     import json
 
+    from repro.util import use_compile_cache
+
     from .common import Reporter
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -222,6 +219,7 @@ def main(argv=None) -> int:
         "or the lease plane all collapse it)",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     backends = [b.strip() for b in args.backends.split(",") if b.strip()]
     engines = (

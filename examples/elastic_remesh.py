@@ -39,7 +39,7 @@ def place(state, mesh):
 def run_steps(state, cfg, opt, dcfg, mesh, start, n):
     step = jax.jit(make_train_step(cfg, opt))
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         state = place(state, mesh)
         for i in range(start, start + n):
             state, m = step(state, synthetic_batch(dcfg, i, cfg))

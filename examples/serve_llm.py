@@ -35,6 +35,11 @@ watches tokens stream in *before* completion, and a SIGKILL landing on
 engine A while its slots are mid-decode.  Engine B reaps A's lapsed
 leases and finishes the job: every request completes exactly once.
 
+This is a CPU crash-safety demo: it needs two engine *processes* (one to
+SIGKILL), and two processes cannot share one accelerator chip, so the
+children run with ``JAX_PLATFORMS=cpu``.  On a chip, serve with one process
+and one engine per device (``python chip_smoke.py`` at the repo root).
+
 Run:  PYTHONPATH=src python examples/serve_llm.py
 """
 
@@ -52,7 +57,7 @@ N_REQ = 8
 
 
 def _spawn_engine(kv_root: str, obj_root: str, engine_id: str) -> subprocess.Popen:
-    env = dict(os.environ, PYTHONPATH=_SRC)
+    env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "repro.launch.serve",

@@ -8,7 +8,9 @@ cache through VMEM.  TPU adaptation:
   * all q heads of one KV group are processed together as a (group, D) tile —
     GQA turns the dot into a (group x D) @ (D x block_k) MXU matmul instead
     of `group` separate vector dots, recovering MXU utilization;
-  * variable cache lengths handled by masking against `cache_len`.
+  * variable cache lengths handled by masking against `cache_len`, which
+    rides in SMEM as a scalar-prefetch operand (a (B,) vector cannot be
+    blocked per row in VMEM: its block would be (1,), not (8, 128)-tiled).
 
 For sequence-sharded caches (tp > kv_heads), `ops.decode_attention` wraps
 this with a partial-softmax (m, l, acc) tree-combine over the model axis.
@@ -29,7 +31,7 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    cache_len_ref,  # (1,) int32 (SMEM-ish prefetch; one per batch row)
+    cache_len_ref,  # (B,) int32 scalar prefetch (SMEM), one per batch row
     q_ref,  # (group, D)
     k_ref,  # (block_k, D)
     v_ref,  # (block_k, D)
@@ -52,7 +54,7 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    clen = cache_len_ref[0]
+    clen = cache_len_ref[pl.program_id(0)]
     blk_start = kj * block_k
     # live block: overlaps [max(0, clen-window), clen)
     lo = jnp.maximum(0, clen - window) if (window is not None and window > 0) else 0
@@ -103,7 +105,7 @@ def decode_attention_pallas(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None: interpret unless on a TPU
 ) -> jnp.ndarray:
     B, H, D = q.shape
     _, S, K, _ = k_cache.shape
@@ -127,7 +129,9 @@ def decode_attention_pallas(
     qg = q.reshape(B, K, group, D)  # group q-heads by kv head
     kt = k_cache.transpose(0, 2, 1, 3)  # (B, K, S, D)
     vt = v_cache.transpose(0, 2, 1, 3)
-    clen = cache_len.astype(jnp.int32).reshape(B, 1)
+    clen = cache_len.astype(jnp.int32).reshape(B)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
 
     kernel = functools.partial(
         _decode_kernel,
@@ -139,20 +143,24 @@ def decode_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, K, n_k),
-        in_specs=[
-            pl.BlockSpec((None, 1), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((None, None, group, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, group, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K, n_k),
+            in_specs=[
+                pl.BlockSpec((None, None, group, D), lambda b, h, j, cl: (b, h, 0, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j, cl: (b, h, j, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j, cl: (b, h, j, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, None, group, D), lambda b, h, j, cl: (b, h, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((group,), jnp.float32),
+                pltpu.VMEM((group,), jnp.float32),
+                pltpu.VMEM((group, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, K, group, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

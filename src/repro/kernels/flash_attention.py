@@ -13,7 +13,9 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
     window=4096 local layers of gemma2 where >87% of blocks are masked at 32k.
 
 Supports: causal or full, sliding window, logit softcap (gemma2), q_offset
-(decode/prefill continuation).  fp32 accumulation throughout.
+(decode/prefill continuation), any sequence lengths (padded to the block,
+padded keys masked), value head dim != query head dim (MLA).  fp32
+accumulation throughout.
 """
 
 from __future__ import annotations
@@ -34,12 +36,12 @@ def _flash_kernel(
     # refs
     q_ref,  # (block_q, D)
     k_ref,  # (block_k, D)
-    v_ref,  # (block_k, D)
-    o_ref,  # (block_q, D)
+    v_ref,  # (block_k, Dv)
+    o_ref,  # (block_q, Dv)
     # scratch
     m_scr,  # (block_q,) running max
     l_scr,  # (block_q,) running denom
-    acc_scr,  # (block_q, D) running numerator
+    acc_scr,  # (block_q, Dv) running numerator
     *,
     scale: float,
     causal: bool,
@@ -49,6 +51,7 @@ def _flash_kernel(
     block_q: int,
     block_k: int,
     num_k_blocks: int,
+    kv_len: Optional[int],  # true key count when keys were padded, else None
 ):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
@@ -66,6 +69,8 @@ def _flash_kernel(
     q_lo, q_hi = qi * block_q + q_offset, qi * block_q + q_offset + block_q - 1
     k_lo, k_hi = kj * block_k, kj * block_k + block_k - 1
     live = True
+    if kv_len is not None:
+        live = jnp.logical_and(live, k_lo < kv_len)
     if causal:
         live = jnp.logical_and(live, k_lo <= q_hi)
     if window is not None and window > 0:
@@ -82,6 +87,8 @@ def _flash_kernel(
             s = logit_cap * jnp.tanh(s / logit_cap)
 
         mask = jnp.ones((block_q, block_k), dtype=jnp.bool_)
+        if kv_len is not None:
+            mask &= k_pos[None, :] < kv_len
         if causal:
             mask &= k_pos[None, :] <= q_pos[:, None]
         if window is not None and window > 0:
@@ -112,7 +119,7 @@ def _flash_kernel(
 def flash_attention_pallas(
     q: jnp.ndarray,  # (B, Sq, H, D)
     k: jnp.ndarray,  # (B, Sk, K, D)
-    v: jnp.ndarray,  # (B, Sk, K, D)
+    v: jnp.ndarray,  # (B, Sk, K, Dv)
     *,
     causal: bool = True,
     window: Optional[int] = None,
@@ -121,19 +128,31 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None: interpret unless on a TPU
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
+    Dv = v.shape[-1]
     assert H % K == 0
     group = H // K
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
 
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
-    assert Sq % block_q == 0 and Sk % block_k == 0, (Sq, block_q, Sk, block_k)
-    n_q = Sq // block_q
-    n_k = Sk // block_k
+    # Pad both lengths up to whole blocks.  Padded query rows are computed
+    # and sliced off; padded keys are masked by `kv_len` (under causal
+    # masking with q_offset >= 0 they already sit past every real query,
+    # but a full or offset attention would otherwise see them).
+    pad_q, pad_k = (-Sq) % block_q, (-Sk) % block_k
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+    n_q = (Sq + pad_q) // block_q
+    n_k = (Sk + pad_k) // block_k
 
     # (B, H, S, D) layout for clean 2D blocks
     qt = q.transpose(0, 2, 1, 3)
@@ -150,6 +169,7 @@ def flash_attention_pallas(
         block_q=block_q,
         block_k=block_k,
         num_k_blocks=n_k,
+        kv_len=Sk if pad_k else None,
     )
 
     out = pl.pallas_call(
@@ -158,14 +178,14 @@ def flash_attention_pallas(
         in_specs=[
             pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((None, None, block_k, D), lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, h, i, j: (b, h // group, j, 0)),
+            pl.BlockSpec((None, None, block_k, Dv), lambda b, h, i, j: (b, h // group, j, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pad_q, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -173,4 +193,4 @@ def flash_attention_pallas(
         interpret=interpret,
         name="flash_attention",
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)  # back to (B, Sq, H, D)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)  # back to (B, Sq, H, Dv)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -104,9 +105,11 @@ def mlstm_pallas(
     *,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None: interpret unless on a TPU
 ) -> jnp.ndarray:
     B, S, H, D = q.shape
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     scale = 1.0 / math.sqrt(D)
     block_q = min(block_q, S)
     block_k = min(block_k, S)

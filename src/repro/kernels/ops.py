@@ -9,9 +9,9 @@ Three tiers per op:
     interpret mode) and what CPU smoke training runs.  Differentiable.
   * naive reference in ref.py — ground truth for tests only.
 
-Selection: TPU backend -> Pallas; otherwise chunked jnp.  `force_ref=True`
-in tests pins the naive oracle.  The env knob REPRO_FORCE_PALLAS_INTERPRET=1
-exercises interpret-mode Pallas end-to-end inside models (slow; CI only).
+Selection: TPU backend -> Pallas, always compiled (the kernels choose
+interpret mode only off the TPU, and attention pads any length to the
+kernel's block); otherwise chunked jnp.
 """
 
 from __future__ import annotations
@@ -35,13 +35,7 @@ NEG_INF = -1e30
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET") == "1":
-        return True
     return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -136,25 +130,14 @@ def flash_attention(
     logit_cap: Optional[float] = None,
     q_offset: int = 0,
     scale: Optional[float] = None,
-    force_ref: bool = False,
     block_k: int = 4096,
 ) -> jnp.ndarray:
     """(B, Sq, H, D) x (B, Sk, K, D)^2 -> (B, Sq, H, D)."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if force_ref:
-        return ref.mha_reference(
-            q, k, v, causal=causal, window=window, logit_cap=logit_cap,
-            q_offset=q_offset, scale=scale,
-        )
-    if (
-        _use_pallas()
-        and q.shape[1] % 128 == 0
-        and k.shape[1] % 128 == 0
-        and q.shape[-1] == v.shape[-1]  # Pallas kernel assumes Dv == Dqk
-    ):
+    if _use_pallas():
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, logit_cap=logit_cap,
-            q_offset=q_offset, scale=scale, interpret=_interpret(),
+            q_offset=q_offset, scale=scale,
         )
     if q.shape[1] * k.shape[1] <= 256 * 256:
         return ref.mha_reference(
@@ -176,21 +159,20 @@ def decode_attention(
     logit_cap: Optional[float] = None,
     window: Optional[int] = None,
     scale: Optional[float] = None,
-    force_ref: bool = False,
 ) -> jnp.ndarray:
     """One-token attention against the KV cache.
 
     The jnp path is written reduction-style so that a sequence-sharded cache
     under pjit turns the softmax reductions into all-reduces (flash-decoding
     across the model axis without shard_map)."""
-    if force_ref or not _use_pallas():
+    if not _use_pallas():
         return ref.decode_attention_reference(
             q, k_cache, v_cache, cache_len,
             logit_cap=logit_cap, window=window, scale=scale,
         )
     return decode_attention_pallas(
         q, k_cache, v_cache, cache_len,
-        logit_cap=logit_cap, window=window, scale=scale, interpret=_interpret(),
+        logit_cap=logit_cap, window=window, scale=scale,
     )
 
 
@@ -264,14 +246,11 @@ def ssd_scan(
     D: Optional[jnp.ndarray] = None,
     *,
     chunk: int = 128,
-    force_ref: bool = False,
     return_state: bool = False,
 ):
     S = x.shape[1]
-    if force_ref:
-        return ref.ssd_reference(x, dt, A, Bmat, Cmat, D, return_state=return_state)
     if _use_pallas() and S % chunk == 0 and not return_state:
-        return ssd_pallas(x, dt, A, Bmat, Cmat, D, chunk=chunk, interpret=_interpret())
+        return ssd_pallas(x, dt, A, Bmat, Cmat, D, chunk=chunk)
     chunk = min(chunk, S)
     pad = (-S) % chunk
     if pad:  # pad to chunk multiple (padded dt=0 -> identity steps)
@@ -387,14 +366,11 @@ def mlstm_parallel(
     i_gate: jnp.ndarray,
     f_gate: jnp.ndarray,
     *,
-    force_ref: bool = False,
     block_k: int = 2048,
 ) -> jnp.ndarray:
     S = q.shape[1]
-    if force_ref:
-        return ref.mlstm_reference(q, k, v, i_gate, f_gate)
     if _use_pallas() and S % 128 == 0:
-        return mlstm_pallas(q, k, v, i_gate, f_gate, interpret=_interpret())
+        return mlstm_pallas(q, k, v, i_gate, f_gate)
     if S <= 256:
         return ref.mlstm_reference(q, k, v, i_gate, f_gate)
     if S % block_k != 0:

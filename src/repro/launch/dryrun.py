@@ -155,7 +155,7 @@ def lower_cell(
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, group_size=moe_group))
     mesh = make_production_mesh(multi_pod=multi_pod)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         batch_structs = input_specs(cfg, shape, mesh)
         if shape.kind == "train":
             state_structs, opt = _state_structs(cfg, mesh)
@@ -271,7 +271,7 @@ def _lower_variant(
     cfg: ModelConfig, shape: ShapeSpec, mesh, *, microbatches=1, remat=True, compile=True
 ):
     """Lower (and optionally compile) one config variant for the given shape."""
-    with mesh:
+    with jax.set_mesh(mesh):
         batch_structs = input_specs(cfg, shape, mesh)
         if shape.kind == "train":
             state_structs, opt = _state_structs(cfg, mesh)

@@ -28,37 +28,37 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Optional
 
 import jax
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
-from repro.configs import CONFIGS
+from repro.configs import CONFIGS, ModelConfig
 from repro.models import init_params
 from repro.serve import ContinuousEngine, ServeConfig
 from repro.serve import request_plane as rp
 from repro.storage import FileBackend, FileKVStore, KVStore, ObjectStore
+from repro.util import use_compile_cache
 
 
-def _build_engine(args) -> ContinuousEngine:
-    cfg = CONFIGS[args.arch]
-    if args.reduced:
-        cfg = cfg.reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    scfg = ServeConfig(
-        max_batch=args.batch,
-        max_len=args.max_len,
-        max_new_tokens=args.new_tokens,
-        decode_chunk=args.decode_chunk,
-        n_queues=args.queues,
-        lease_timeout_s=args.lease_timeout,
-    )
-    engine = ContinuousEngine(cfg, params, scfg)
-    # compile decode + the single-request prefill shape before READY
-    engine.admit([("warm", [1, 2, 3], 2)])
-    while engine.n_live():
-        engine.step_chunk()
-    for k in engine.stats:
-        engine.stats[k] = 0
+def build_engine(
+    cfg: ModelConfig,
+    scfg: ServeConfig,
+    *,
+    device: Optional[jax.Device] = None,
+    seed: int = 0,
+) -> ContinuousEngine:
+    """A warmed engine with random weights from ``seed`` on ``device``.
+
+    The weights are drawn under ``jax.jit`` straight into the device's
+    memory: eagerly, each stacked tensor would first exist in float32."""
+    device = jax.devices()[0] if device is None else device
+    params = jax.jit(
+        init_params, static_argnums=0, out_shardings=SingleDeviceSharding(device)
+    )(cfg, jax.random.PRNGKey(seed))
+    engine = ContinuousEngine(cfg, params, scfg, device=device)
+    engine.warm()  # compile before READY, outside any lease
     return engine
 
 
@@ -95,7 +95,19 @@ def main() -> None:
         kv = KVStore(num_shards=2)
         store = ObjectStore()
 
-    engine = _build_engine(args)
+    use_compile_cache()
+    cfg = CONFIGS[args.arch]
+    if args.reduced:
+        cfg = cfg.reduced()
+    scfg = ServeConfig(
+        max_batch=args.batch,
+        max_len=args.max_len,
+        max_new_tokens=args.new_tokens,
+        decode_chunk=args.decode_chunk,
+        n_queues=args.queues,
+        lease_timeout_s=args.lease_timeout,
+    )
+    engine = build_engine(cfg, scfg)
     print(f"READY {args.engine_id}", flush=True)
 
     if args.demo_requests:

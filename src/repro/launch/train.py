@@ -21,6 +21,7 @@ from repro.core import WrenExecutor
 from repro.data import DataConfig, synthetic_batch
 from repro.train import ElasticTrainConfig, adamw, cosine_schedule, train_elastic
 from repro.train import checkpoint as ck
+from repro.util import use_compile_cache
 
 
 def main() -> None:
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--run", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = CONFIGS[args.arch]
     if args.reduced:

@@ -25,21 +25,9 @@ TP = "tp"  # tensor/expert-parallel logical axis -> "model"
 
 
 def current_mesh() -> Optional[Mesh]:
-    try:  # jax >= 0.8: use_mesh / abstract mesh context
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    try:  # `with mesh:` (Mesh context manager) path
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # noqa: BLE001
-        return None
+    """The mesh entered with `jax.set_mesh`, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def axis_map() -> str:

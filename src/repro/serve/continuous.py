@@ -5,19 +5,29 @@ iteration runs a single jitted one-token decode step over all slots — live
 or not — with a per-slot `cache_len` vector (the decode kernels mask
 variable lengths, so prompts are never left-padded to a common length).
 Finished rows are evicted immediately; freed slots are refilled at chunk
-boundaries by an interleaved *prefill microbatch*: new prompts prefill
-into a fresh small cache which is scattered into the persistent one with
+boundaries by interleaved prefills: each new prompt prefills alone into a
+fresh one-row cache which is scattered into the persistent one with
 `cache_update.insert_rows` (whole-row replacement — a new occupant can
 never read its predecessor's KV).  The running batch never drains.
 
 Shapes are jit-stable by construction: the decode step always sees
 (max_batch, 1) tokens against the (max_batch, …) cache, so it compiles
-exactly once; prefill compiles per (group size, bucketed prompt length).
+exactly once; prefill compiles once per bucketed prompt length.  A prompt
+never shares a prefill program with others: on a TPU, prompts prefilled in
+groups of different sizes came out with different greedy tokens (the
+programs for n and m rows round differently, and with bf16 logits a near
+tie flips the argmax).  One program per bucket keeps every request's tokens
+a function of the request alone, which is what lets a peer re-serve it
+byte-identically.
 
 `ContinuousEngine.run` plugs the slot machinery into the lease-driven
 request plane (`serve.request_plane`): lease -> admit -> decode chunk ->
 stream -> publish, with lease heartbeats and expired-lease reaping riding
 the chunk cadence.
+
+An engine serves on one device: its params, persistent cache and every
+prefill's cache live there.  Several engines in one process, one per
+device, share a queue like engines in separate processes do.
 """
 
 from __future__ import annotations
@@ -29,6 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.models import cache_batch_axes, decode_step, init_cache, prefill
@@ -53,21 +64,34 @@ class Slot:
 class ContinuousEngine:
     """Slot-based continuous-batching engine over one persistent cache."""
 
-    def __init__(self, cfg: ModelConfig, params: Any, scfg: ServeConfig) -> None:
+    def __init__(
+        self,
+        cfg: ModelConfig,
+        params: Any,
+        scfg: ServeConfig,
+        device: Optional[jax.Device] = None,
+    ) -> None:
         if cfg.family == "encdec":
             raise NotImplementedError("encdec serving needs encoder inputs per request")
         self.cfg = cfg
-        self.params = params
+        self.device = jax.devices()[0] if device is None else device
+        self.params = jax.device_put(params, self.device)
         self.scfg = scfg
         self._dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[scfg.cache_dtype]
         # recurrent-state families carry prompt state, not a masked KV
-        # buffer: right-pad tokens would corrupt the state, so prefill
-        # microbatches group by *exact* prompt length instead of buckets.
+        # buffer: right-pad tokens would corrupt the state, so their
+        # prompts prefill at *exact* length instead of a bucket.
         self._exact_len = cfg.family in ("ssm", "hybrid")
 
         self._decode = jax.jit(lambda p, t, c, l: decode_step(p, cfg, t, c, l))
         self._prefill = jax.jit(
             lambda p, b, c: prefill(p, cfg, b, c, all_logits=True)
+        )
+        # caches are built in place on the device (no host or device-0 copy)
+        self._new_cache = jax.jit(
+            lambda n: init_cache(cfg, n, scfg.max_len, cache_dtype=self._dtype),
+            static_argnums=0,
+            out_shardings=SingleDeviceSharding(self.device),
         )
         axes = cache_batch_axes(cfg, scfg.max_len, self._dtype)
         self._insert = jax.jit(
@@ -77,7 +101,7 @@ class ContinuousEngine:
         )
 
         B = scfg.max_batch
-        self.cache = init_cache(cfg, B, scfg.max_len, cache_dtype=self._dtype)
+        self.cache = self._new_cache(B)
         self.cache_lens = np.zeros((B,), np.int32)
         self.tokens = np.zeros((B,), np.int32)  # next token fed per slot
         self.steps = np.zeros((B,), np.int32)  # per-request sample index
@@ -88,9 +112,11 @@ class ContinuousEngine:
             "tokens_out": 0,
             "admissions": 0,
             "mid_batch_admissions": 0,
-            "prefill_groups": 0,
             "decode_steps": 0,
         }
+
+    def _put(self, host_array: np.ndarray) -> jax.Array:
+        return jax.device_put(host_array, self.device)
 
     # ---- slot bookkeeping ------------------------------------------------
 
@@ -110,7 +136,7 @@ class ContinuousEngine:
         self.steps[i] = 0
         self.keys[i] = 0
 
-    # ---- admission: interleaved prefill microbatch -----------------------
+    # ---- admission: interleaved prefills ---------------------------------
 
     def _pad_len(self, plen: int) -> int:
         if self._exact_len:
@@ -134,54 +160,46 @@ class ContinuousEngine:
             return 0
         was_live = self.n_live() > 0
         scfg = self.scfg
-        groups: Dict[int, List[Tuple[str, Sequence[int], int]]] = {}
         for req_id, prompt, max_new in requests:
             prompt = list(prompt)[: scfg.max_len - 1]  # leave room to decode
-            groups.setdefault(self._pad_len(len(prompt)), []).append(
-                (req_id, prompt, max_new)
-            )
-        for Lpad, group in groups.items():
-            n = len(group)
-            toks = np.zeros((n, Lpad), np.int32)
-            lens = np.zeros((n,), np.int32)
-            for j, (_, prompt, _) in enumerate(group):
-                toks[j, : len(prompt)] = prompt
-                lens[j] = len(prompt)
-            small = init_cache(self.cfg, n, scfg.max_len, cache_dtype=self._dtype)
+            n_tok = len(prompt)
+            toks = np.zeros((1, self._pad_len(n_tok)), np.int32)
+            toks[0, :n_tok] = prompt
             logits_all, small, _ = self._prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, small
+                self.params, {"tokens": self._put(toks)}, self._new_cache(1)
             )
-            # each row's logits at its own last true token
-            last = jnp.take_along_axis(
-                logits_all, jnp.asarray(lens - 1)[:, None, None], axis=1
-            )[:, 0]  # (n, V)
-            slot_ids = [free.pop(0) for _ in group]
-            self.cache = self._insert(self.cache, small, jnp.asarray(slot_ids))
-            gkeys = None
+            last = logits_all[:, n_tok - 1]  # (1, V) at the last true token
+            i = free.pop(0)
+            self.cache = self._insert(self.cache, small, self._put(np.asarray([i])))
+            keys = None
             if scfg.temperature > 0:
-                gkeys = request_keys([rp.request_seed(r) for r, _, _ in group])
-            tok0 = np.asarray(sample_tokens(last, gkeys, 0, scfg.temperature))
+                keys = request_keys([rp.request_seed(req_id)])
+            tok0 = int(np.asarray(sample_tokens(last, keys, 0, scfg.temperature))[0])
             now = time.time()
-            for j, (req_id, prompt, max_new) in enumerate(group):
-                i = slot_ids[j]
-                s = Slot(req_id, len(prompt), max_new, t_admit=now, t_first=now)
-                s.out.append(int(tok0[j]))
-                if (
-                    len(s.out) >= max_new
-                    or (scfg.eos_id >= 0 and s.out[-1] == scfg.eos_id)
-                ):
-                    s.done = True
-                self.slots[i] = s
-                self.cache_lens[i] = lens[j]
-                self.tokens[i] = tok0[j]
-                self.steps[i] = 1
-                if gkeys is not None:
-                    self.keys[i] = np.asarray(gkeys[j])
-            self.stats["prefill_groups"] += 1
+            s = Slot(req_id, n_tok, max_new, t_admit=now, t_first=now)
+            s.out.append(tok0)
+            if len(s.out) >= max_new or (scfg.eos_id >= 0 and tok0 == scfg.eos_id):
+                s.done = True
+            self.slots[i] = s
+            self.cache_lens[i] = n_tok
+            self.tokens[i] = tok0
+            self.steps[i] = 1
+            if keys is not None:
+                self.keys[i] = np.asarray(keys[0])
         self.stats["admissions"] += len(requests)
         if was_live:
             self.stats["mid_batch_admissions"] += len(requests)
         return len(requests)
+
+    def warm(self) -> None:
+        """Compile decode, prefill at the first prompt bucket and the slot
+        insert before any request is leased: a compile inside `run` would
+        stall heartbeats past the lease timeout.  Resets the stats."""
+        self.admit([("warm", [1, 2, 3], 2)])
+        while self.n_live():
+            self.step_chunk()
+        for k in self.stats:
+            self.stats[k] = 0
 
     # ---- the decode chunk ------------------------------------------------
 
@@ -217,12 +235,12 @@ class ContinuousEngine:
                 break
             logits, self.cache = self._decode(
                 self.params,
-                jnp.asarray(self.tokens[:, None]),
+                self._put(self.tokens[:, None]),
                 self.cache,
-                jnp.asarray(self.cache_lens),
+                self._put(self.cache_lens),
             )
             self.stats["decode_steps"] += 1
-            keys = jnp.asarray(self.keys) if scfg.temperature > 0 else None
+            keys = self._put(self.keys) if scfg.temperature > 0 else None
             toks = np.asarray(
                 sample_tokens(logits[:, 0], keys, self.steps, scfg.temperature)
             )

@@ -1,6 +1,25 @@
 """Dependency-free utilities shared across layers."""
 
 import os
+from pathlib import Path
+
+# the repository root: src/repro/util.py -> ../../
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    Entry points call this before their first compile.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing
+    is changed.  Otherwise the cache lives in ``.jax_cache/`` at the
+    repository root: a fixed path, so the next run of any entry point from
+    this checkout finds what the last one compiled."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(REPO_ROOT / ".jax_cache"))
 
 
 def scan_unroll():

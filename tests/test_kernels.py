@@ -37,6 +37,8 @@ FLASH_CASES = [
     (1, 128, 128, 2, 2, 64, True, None, 50.0, 0),      # softcap (gemma2)
     (1, 128, 256, 4, 4, 64, True, None, None, 128),    # continuation offset
     (1, 128, 128, 2, 1, 64, False, None, None, 0),     # encoder (full)
+    (2, 100, 100, 4, 2, 64, True, None, None, 0),      # length not a block multiple
+    (1, 72, 200, 2, 1, 64, False, None, None, 0),      # full attention, padded keys
 ]
 
 
@@ -78,13 +80,17 @@ def test_chunked_attention_matches_ref(B, S, K, D, causal):
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=1e-4)
 
 
-def test_attention_mla_head_dims():
-    """Dv != Dqk (MLA): jnp path must handle it."""
+@pytest.mark.parametrize("impl", ["ops", "pallas"])
+def test_attention_mla_head_dims(impl):
+    """Dv != Dqk (MLA): the jnp path and the kernel must both handle it."""
     rng = np.random.default_rng(1)
     q = _rand(rng, (2, 300, 8, 192))
     k = _rand(rng, (2, 300, 8, 192))
     v = _rand(rng, (2, 300, 8, 128))
-    out = ops.flash_attention(q, k, v, causal=True)
+    if impl == "ops":
+        out = ops.flash_attention(q, k, v, causal=True)
+    else:
+        out = flash_attention_pallas(q, k, v, causal=True)
     exp = ref.mha_reference(q, k, v, causal=True)
     assert out.shape == (2, 300, 8, 128)
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=1e-4)

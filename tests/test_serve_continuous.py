@@ -13,6 +13,7 @@ The pins, straight from the PR contract:
     duplicated/overwritten results (real subprocess, shared file backend).
 """
 
+import json
 import os
 import signal
 import subprocess
@@ -324,3 +325,68 @@ def test_sigkill_engine_zero_lost_zero_duplicated(tmp_path):
         assert now["tokens"] == rec["tokens"], k
     assert eng_b.stats["served"] >= 1
     kv.close()
+
+
+# ---------------------------------------------------------------------------
+# one process, one engine per device
+# ---------------------------------------------------------------------------
+
+_MULTI_DEVICE_SCRIPT = r"""
+import json, threading
+import jax
+import numpy as np
+from repro.configs import CONFIGS
+from repro.launch.serve import build_engine
+from repro.serve import ServeConfig
+from repro.serve import request_plane as rp
+from repro.storage import KVStore, ObjectStore
+
+cfg = CONFIGS["qwen3-32b"].reduced()
+scfg = ServeConfig(max_batch=2, max_len=64, max_new_tokens=6, decode_chunk=2,
+                   prefill_bucket=8)
+devices = jax.devices()
+assert len(devices) == 4, devices
+engines = [build_engine(cfg, scfg, device=d) for d in devices]
+for d, e in zip(devices, engines):
+    held = {x for a in jax.tree_util.tree_leaves((e.params, e.cache)) for x in a.devices()}
+    assert held == {d}, (d, held)
+rng = np.random.default_rng(0)
+prompts = {f"r{i}": rng.integers(0, cfg.vocab_size, size=int(rng.integers(3, 8))).tolist()
+           for i in range(8)}
+
+def serve(engs):
+    store, kv = ObjectStore(), KVStore(num_shards=1)
+    threads = [threading.Thread(target=e.run, args=(store, kv),
+                                kwargs=dict(engine_id=f"e{i}", idle_timeout_s=2.0))
+               for i, e in enumerate(engs)]
+    for t in threads:
+        t.start()
+    rp.submit_many(store, kv, prompts)  # engines are parked in blpop
+    res = rp.get_results(store, list(prompts), timeout_s=120)
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    return res
+
+solo = serve(engines[:1])
+multi = serve(engines)
+assert all(multi[r]["tokens"] == solo[r]["tokens"] for r in prompts)
+print(json.dumps(sorted({multi[r]["engine"] for r in prompts})))
+"""
+
+
+def test_engines_one_per_device_match_one_engine():
+    """Four engines in one process, one per (virtual) device, drain one
+    queue: each holds its arrays on its own device, and greedy tokens equal
+    the one-engine pass (per-request determinism across devices)."""
+    env = dict(
+        os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", _MULTI_DEVICE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=560,
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    engines = json.loads(out.stdout.strip().splitlines()[-1])  # who served
+    assert len(engines) >= 2, engines

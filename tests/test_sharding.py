@@ -36,8 +36,9 @@ def test_param_pspec_rules():
         from jax.sharding import PartitionSpec as P
         from repro.configs import CONFIGS
         from repro.models import init_params
+        from repro.launch.mesh import make_mesh
         from repro.models.sharding import param_pspec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(dp=2, tp=4)
         cfg = CONFIGS["llama3-8b"].reduced()
         shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
         specs = param_pspec(mesh, shapes)
@@ -61,6 +62,7 @@ def test_tiny_mesh_train_lowering_with_collectives():
         import jax, jax.numpy as jnp, re
         from jax.sharding import NamedSharding
         from repro.configs import CONFIGS
+        from repro.launch.mesh import make_mesh
         from repro.launch.shardings import batch_pspec, state_pspec, to_shardings
         from repro.train import adamw, make_train_step
         from repro.train.train_step import TrainState
@@ -72,7 +74,7 @@ def test_tiny_mesh_train_lowering_with_collectives():
             d_model=256, n_heads=8, n_kv_heads=4, head_dim=32, d_ff=512,
             vocab_size=512, n_layers=4,
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(dp=2, tp=4)
         opt = adamw(1e-3)
         def make():
             p = init_params(cfg, jax.random.PRNGKey(0))
@@ -91,7 +93,7 @@ def test_tiny_mesh_train_lowering_with_collectives():
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             batch, bsh)
         step = make_train_step(cfg, opt)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(step, donate_argnums=(0,)).lower(
                 state_structs, batch_structs).compile()
         txt = compiled.as_text()
@@ -110,6 +112,7 @@ def test_tiny_mesh_decode_lowering():
         """
         import jax, jax.numpy as jnp, dataclasses
         from repro.configs import CONFIGS
+        from repro.launch.mesh import make_mesh
         from repro.launch.shardings import cache_pspec, state_pspec, to_shardings
         from repro.models import decode_step, init_cache, init_params
 
@@ -118,7 +121,7 @@ def test_tiny_mesh_decode_lowering():
             d_model=256, n_heads=8, n_kv_heads=4, head_dim=32, d_ff=512,
             vocab_size=512, n_layers=2,
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(dp=2, tp=4)
         params_shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
         psh = to_shardings(mesh, state_pspec(mesh, params_shapes))
         params_structs = jax.tree_util.tree_map(
@@ -130,7 +133,7 @@ def test_tiny_mesh_decode_lowering():
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             cache_shapes, csh)
         fn = lambda p, t, c, l: decode_step(p, cfg, t, c, l)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, donate_argnums=(2,)).lower(
                 params_structs,
                 jax.ShapeDtypeStruct((8, 1), jnp.int32),
@@ -188,7 +191,7 @@ def test_checkpoint_reshard_across_meshes():
         mesh_a = make_mesh(dp=4, tp=2)
         state = init_train_state(cfg, opt, jax.random.PRNGKey(0))
         step = jax.jit(make_train_step(cfg, opt))
-        with mesh_a:
+        with jax.set_mesh(mesh_a):
             state = place(state, mesh_a)
             first = None
             for i in range(6):
@@ -199,7 +202,7 @@ def test_checkpoint_reshard_across_meshes():
         mesh_b = make_mesh(dp=2, tp=4)
         loaded, _, _ = ck.load(store, "rt")
         state_b = TrainState(*loaded)
-        with mesh_b:
+        with jax.set_mesh(mesh_b):
             state_b = place(state_b, mesh_b)
             state_b, m = step(state_b, synthetic_batch(dcfg, 6, cfg))
         resumed = float(m["loss"])
