@@ -130,9 +130,17 @@ def main() -> None:
         f"{stats['tokens_out']} tokens in {dt:.1f}s "
         f"({stats['tokens_out'] / max(dt, 1e-9):.1f} tok/s; "
         f"{stats['mid_batch_admissions']} mid-batch admissions, "
-        f"{stats['decode_steps']} decode steps)",
+        f"{stats['decode_steps']} decode steps; "
+        f"mean lease wait {_mean_ms(stats, 'lease_wait_ns', 'leased'):.2f} ms, "
+        f"first-token hold "
+        f"{_mean_ms(stats, 'first_token_hold_ns', 'first_tokens_streamed'):.2f} ms, "
+        f"host {_mean_ms(stats, 'decode_host_ns', 'decode_steps'):.3f} ms per decode step)",
         flush=True,
     )
+
+
+def _mean_ms(stats, total_ns: str, count: str) -> float:
+    return stats[total_ns] / max(stats[count], 1) * 1e-6
 
 
 if __name__ == "__main__":

@@ -25,6 +25,15 @@ request plane (`serve.request_plane`): lease -> admit -> decode chunk ->
 stream -> publish, with lease heartbeats and expired-lease reaping riding
 the chunk cadence.
 
+The loop measures itself.  `stats` keeps counters that are always on: the
+requests leased and their summed wait from submit to lease
+(`lease_wait_ns`), the first tokens streamed and their summed hold from
+sampling to the return of the push that carried them
+(`first_token_hold_ns`), and the engine thread's time per decode step
+outside the token read-back (`decode_host_ns`, over `decode_steps`).
+While a profiler session is on, the loop also opens `serve.*` spans
+(`serve.tracing`); docs/ARCHITECTURE.md lists them and how they nest.
+
 An engine serves on one device: its params, persistent cache and every
 prefill's cache live there.  Several engines in one process, one per
 device, share a queue like engines in separate processes do.
@@ -42,11 +51,13 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
-from repro.models import cache_batch_axes, decode_step, init_cache, prefill
+from repro.models import cache_batch_axes, decode_step, init_cache
+from repro.models import prefill as model_prefill
 from repro.models.cache_update import insert_rows
 
 from . import request_plane as rp
 from .engine import ServeConfig, request_keys, sample_tokens
+from .tracing import tracer
 
 
 @dataclass
@@ -57,8 +68,8 @@ class Slot:
     out: List[int] = field(default_factory=list)  # sampled tokens so far
     streamed: int = 0  # tokens already pushed to serve/stream/{req}
     done: bool = False
-    t_admit: float = 0.0
     t_first: float = 0.0  # wall time of the first sampled token (TTFT)
+    t_first_ns: int = 0  # the same instant on time.perf_counter_ns
 
 
 class ContinuousEngine:
@@ -83,22 +94,32 @@ class ContinuousEngine:
         # prompts prefill at *exact* length instead of a bucket.
         self._exact_len = cfg.family in ("ssm", "hybrid")
 
-        self._decode = jax.jit(lambda p, t, c, l: decode_step(p, cfg, t, c, l))
-        self._prefill = jax.jit(
-            lambda p, b, c: prefill(p, cfg, b, c, all_logits=True)
-        )
-        # caches are built in place on the device (no host or device-0 copy)
-        self._new_cache = jax.jit(
-            lambda n: init_cache(cfg, n, scfg.max_len, cache_dtype=self._dtype),
-            static_argnums=0,
-            out_shardings=SingleDeviceSharding(self.device),
-        )
+        # named functions: the programs are jit_decode, jit_prefill,
+        # jit_new_cache and jit_insert in a profile
+        def decode(p, t, c, l):
+            return decode_step(p, cfg, t, c, l)
+
+        def prefill(p, b, c):
+            return model_prefill(p, cfg, b, c, all_logits=True)
+
+        def new_cache(n):
+            return init_cache(cfg, n, scfg.max_len, cache_dtype=self._dtype)
+
         axes = cache_batch_axes(cfg, scfg.max_len, self._dtype)
-        self._insert = jax.jit(
-            lambda big, small, slots: jax.tree_util.tree_map(
+
+        def insert(big, small, slots):
+            return jax.tree_util.tree_map(
                 lambda b, s, ax: insert_rows(b, s, slots, ax), big, small, axes
             )
+
+        self._decode = jax.jit(decode)
+        self._prefill = jax.jit(prefill)
+        # caches are built in place on the device (no host or device-0 copy)
+        self._new_cache = jax.jit(
+            new_cache, static_argnums=0, out_shardings=SingleDeviceSharding(self.device)
         )
+        self._insert = jax.jit(insert)
+        self.engine_id: Optional[str] = None  # set by `run`; tags spans
 
         B = scfg.max_batch
         self.cache = self._new_cache(B)
@@ -113,7 +134,15 @@ class ContinuousEngine:
             "admissions": 0,
             "mid_batch_admissions": 0,
             "decode_steps": 0,
+            "leased": 0,
+            "lease_wait_ns": 0,
+            "first_tokens_streamed": 0,
+            "first_token_hold_ns": 0,
+            "decode_host_ns": 0,
         }
+
+    def _span(self, name: str, **kw):
+        return tracer.span(name, engine_id=self.engine_id, **kw)
 
     def _put(self, host_array: np.ndarray) -> jax.Array:
         return jax.device_put(host_array, self.device)
@@ -159,37 +188,44 @@ class ContinuousEngine:
         if not requests:
             return 0
         was_live = self.n_live() > 0
-        scfg = self.scfg
-        for req_id, prompt, max_new in requests:
-            prompt = list(prompt)[: scfg.max_len - 1]  # leave room to decode
-            n_tok = len(prompt)
-            toks = np.zeros((1, self._pad_len(n_tok)), np.int32)
-            toks[0, :n_tok] = prompt
-            logits_all, small, _ = self._prefill(
-                self.params, {"tokens": self._put(toks)}, self._new_cache(1)
-            )
-            last = logits_all[:, n_tok - 1]  # (1, V) at the last true token
-            i = free.pop(0)
-            self.cache = self._insert(self.cache, small, self._put(np.asarray([i])))
-            keys = None
-            if scfg.temperature > 0:
-                keys = request_keys([rp.request_seed(req_id)])
-            tok0 = int(np.asarray(sample_tokens(last, keys, 0, scfg.temperature))[0])
-            now = time.time()
-            s = Slot(req_id, n_tok, max_new, t_admit=now, t_first=now)
-            s.out.append(tok0)
-            if len(s.out) >= max_new or (scfg.eos_id >= 0 and tok0 == scfg.eos_id):
-                s.done = True
-            self.slots[i] = s
-            self.cache_lens[i] = n_tok
-            self.tokens[i] = tok0
-            self.steps[i] = 1
-            if keys is not None:
-                self.keys[i] = np.asarray(keys[0])
+        with self._span("serve.admit", n=len(requests)):
+            for req_id, prompt, max_new in requests:
+                with self._span("serve.prefill", req=req_id):
+                    self._admit_one(free.pop(0), req_id, prompt, max_new)
         self.stats["admissions"] += len(requests)
         if was_live:
             self.stats["mid_batch_admissions"] += len(requests)
         return len(requests)
+
+    def _admit_one(self, i: int, req_id: str, prompt: Sequence[int], max_new: int) -> None:
+        """Prefill one prompt alone, insert it into slot `i` and sample its
+        first token."""
+        scfg = self.scfg
+        prompt = list(prompt)[: scfg.max_len - 1]  # leave room to decode
+        n_tok = len(prompt)
+        toks = np.zeros((1, self._pad_len(n_tok)), np.int32)
+        toks[0, :n_tok] = prompt
+        logits_all, small, _ = self._prefill(
+            self.params, {"tokens": self._put(toks)}, self._new_cache(1)
+        )
+        last = logits_all[:, n_tok - 1]  # (1, V) at the last true token
+        self.cache = self._insert(self.cache, small, self._put(np.asarray([i])))
+        keys = None
+        if scfg.temperature > 0:
+            keys = request_keys([rp.request_seed(req_id)])
+        sampled = sample_tokens(last, keys, 0, scfg.temperature)
+        with self._span("serve.first_token", req=req_id):
+            tok0 = int(np.asarray(sampled)[0])
+        s = Slot(req_id, n_tok, max_new, t_first=time.time(), t_first_ns=time.perf_counter_ns())
+        s.out.append(tok0)
+        if len(s.out) >= max_new or (scfg.eos_id >= 0 and tok0 == scfg.eos_id):
+            s.done = True
+        self.slots[i] = s
+        self.cache_lens[i] = n_tok
+        self.tokens[i] = tok0
+        self.steps[i] = 1
+        if keys is not None:
+            self.keys[i] = np.asarray(keys[0])
 
     def warm(self) -> None:
         """Compile decode, prefill at the first prompt bucket and the slot
@@ -229,10 +265,29 @@ class ContinuousEngine:
                 touched.append(s)
                 _finish(i, s)
 
-        for _ in range(n_steps):
-            live = [i for i, s in enumerate(self.slots) if s is not None]
-            if not live:
-                break
+        with self._span("serve.chunk", n=n_steps):
+            for _ in range(n_steps):
+                if not self._decode_one(touched, _finish):
+                    break
+
+        chunks: Dict[str, Tuple[int, List[int]]] = {}
+        for s in touched:
+            new = s.out[s.streamed :]
+            if new:
+                chunks[s.req_id] = (s.streamed, new)
+                s.streamed = len(s.out)
+        return finished, chunks
+
+    def _decode_one(self, touched: List[Slot], finish) -> bool:
+        """One decode step over all slots; False when no slot is live.
+        The engine thread's time here outside the token read-back counts
+        into `stats["decode_host_ns"]`."""
+        t0 = time.perf_counter_ns()
+        live = [i for i, s in enumerate(self.slots) if s is not None]
+        if not live:
+            return False
+        scfg = self.scfg
+        with self._span("serve.decode", live=len(live)):
             logits, self.cache = self._decode(
                 self.params,
                 self._put(self.tokens[:, None]),
@@ -241,9 +296,11 @@ class ContinuousEngine:
             )
             self.stats["decode_steps"] += 1
             keys = self._put(self.keys) if scfg.temperature > 0 else None
-            toks = np.asarray(
-                sample_tokens(logits[:, 0], keys, self.steps, scfg.temperature)
-            )
+            sampled = sample_tokens(logits[:, 0], keys, self.steps, scfg.temperature)
+            t_rb = time.perf_counter_ns()
+            with self._span("serve.readback"):
+                toks = np.asarray(sampled)
+            t_rb = time.perf_counter_ns() - t_rb
             for i in live:
                 s = self.slots[i]
                 self.cache_lens[i] += 1  # fed token now resides in the cache
@@ -258,15 +315,9 @@ class ContinuousEngine:
                     or (scfg.eos_id >= 0 and t == scfg.eos_id)
                     or self.cache_lens[i] >= scfg.max_len - 1
                 ):
-                    _finish(i, s)
-
-        chunks: Dict[str, Tuple[int, List[int]]] = {}
-        for s in touched:
-            new = s.out[s.streamed :]
-            if new:
-                chunks[s.req_id] = (s.streamed, new)
-                s.streamed = len(s.out)
-        return finished, chunks
+                    finish(i, s)
+        self.stats["decode_host_ns"] += time.perf_counter_ns() - t0 - t_rb
+        return True
 
     # ---- request-plane loop ----------------------------------------------
 
@@ -285,6 +336,7 @@ class ContinuousEngine:
         and publishing all ride the chunk cadence; an idle engine parks in
         `blpop` on its home queue shard and is pushed awake by a submit."""
         scfg = self.scfg
+        self.engine_id = engine_id
         last_beat = 0.0
         last_reap = 0.0
         idle_deadline = time.monotonic() + idle_timeout_s
@@ -293,20 +345,28 @@ class ContinuousEngine:
                 break
             now = time.time()
             if reap and now - last_reap >= scfg.lease_timeout_s:
-                rp.reap_expired(store, kv, n_queues=scfg.n_queues, worker=engine_id)
+                with self._span("serve.reap"):
+                    rp.reap_expired(store, kv, n_queues=scfg.n_queues, worker=engine_id)
                 last_reap = now
             free = self.free_slots()
             if free:
                 wait_s = 0.0
                 if self.n_live() == 0:
                     wait_s = max(0.0, min(0.5, idle_deadline - time.monotonic()))
-                leased = rp.lease_requests(
-                    store, kv, engine_id, len(free),
-                    lease_timeout_s=scfg.lease_timeout_s,
-                    wait_s=wait_s,
-                    n_queues=scfg.n_queues,
-                )
+                with self._span("serve.lease", n=len(free), wait=wait_s > 0) as sp:
+                    leased = rp.lease_requests(
+                        store, kv, engine_id, len(free),
+                        lease_timeout_s=scfg.lease_timeout_s,
+                        wait_s=wait_s,
+                        n_queues=scfg.n_queues,
+                    )
+                    sp.set(req=[r for r, _ in leased])
                 if leased:
+                    now = time.time()
+                    self.stats["leased"] += len(leased)
+                    self.stats["lease_wait_ns"] += sum(
+                        int((now - float(body["ts"])) * 1e9) for _, body in leased
+                    )
                     self.admit([
                         (r, body["prompt"], int(body.get("max_new", scfg.max_new_tokens)))
                         for r, body in leased
@@ -317,26 +377,35 @@ class ContinuousEngine:
                 continue  # the blpop above is the idle wait — no sleep loop
             idle_deadline = time.monotonic() + idle_timeout_s
 
+            firsts = {s.req_id: s for s in self.slots if s is not None and s.streamed == 0}
             finished, chunks = self.step_chunk()
-            rp.stream_chunks(kv, chunks, worker=engine_id)
+            with self._span("serve.stream", req=list(chunks)):
+                rp.stream_chunks(kv, chunks, worker=engine_id)
+            t_pushed = time.perf_counter_ns()
+            for r, (off, _) in chunks.items():
+                if off == 0:
+                    self.stats["first_tokens_streamed"] += 1
+                    self.stats["first_token_hold_ns"] += t_pushed - firsts[r].t_first_ns
             if finished:
                 t_done = time.time()
-                rp.publish_results(
-                    store, kv, engine_id,
-                    {
-                        r: {
-                            "tokens": s.out,
-                            "t_first": s.t_first,
-                            "t_done": t_done,
-                        }
-                        for r, s in finished.items()
-                    },
-                )
+                with self._span("serve.publish", req=list(finished)):
+                    rp.publish_results(
+                        store, kv, engine_id,
+                        {
+                            r: {
+                                "tokens": s.out,
+                                "t_first": s.t_first,
+                                "t_done": t_done,
+                            }
+                            for r, s in finished.items()
+                        },
+                    )
             now = time.time()
             if now - last_beat >= scfg.heartbeat_interval_s:
-                rp.heartbeat_leases(
-                    kv, engine_id, self.live_req_ids(),
-                    lease_timeout_s=scfg.lease_timeout_s,
-                )
+                with self._span("serve.heartbeat"):
+                    rp.heartbeat_leases(
+                        kv, engine_id, self.live_req_ids(),
+                        lease_timeout_s=scfg.lease_timeout_s,
+                    )
                 last_beat = now
         return dict(self.stats)
