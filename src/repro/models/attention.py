@@ -1,10 +1,12 @@
 """GQA/MHA attention block: train (full), prefill (cache fill), decode
 (single token), optional cross-attention (enc-dec).
 
-KV-cache layout per layer: {"k": (B, Smax, K, hd), "v": (B, Smax, K, hd)};
-`cache_len` is a scalar (aligned batched serving) or a per-row (B,) vector
-(continuous batching: every slot decodes at its own position).  Sharding:
-batch over dp.
+KV-cache layout per layer: {"k": (B, Smax, K, hd), "v": (B, Smax, K, hd)},
+stacked over the stage's layers; the layer gets its place in the stack as a
+`cache_update.LayerCache`, writes its new rows through it and attends
+against its own leaves.  `cache_len` is a scalar (aligned batched serving)
+or a per-row (B,) vector (continuous batching: every slot decodes at its
+own position).  Sharding: batch over dp.
 For the cache's head dim: if K % tp == 0 heads shard over tp; otherwise the
 *sequence* dim shards over tp and the decode softmax reductions become
 all-reduces (flash-decoding across the model axis) — handled purely by
@@ -23,7 +25,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops
 
 from . import layers
-from .cache_update import write_row, write_segment
+from .cache_update import LayerCache
 from .layers import Params, apply_rope, dense_init, rmsnorm, rmsnorm_init
 from .sharding import DP, TP, current_mesh, shard
 
@@ -78,9 +80,14 @@ def _dp_size() -> int:
 
 def cache_logical_spec(cfg: ModelConfig, tp_size: int, batch: int) -> Tuple:
     """(B, S, K, hd) logical axes for the KV cache.  Must agree with
-    launch/shardings.py:cache_pspec."""
+    launch/shardings.py:cache_pspec.
+
+    `tp_size` is the model axis' size, 0 where the mesh has none (or there
+    is no mesh, as on one chip): with 0 or 1 there is nothing to split the
+    heads over, so the heads take the tp slot (a no-op) and the sequence
+    axis stays whole."""
     dp_n = _dp_size()
-    heads_ok = tp_size and cfg.n_kv_heads % tp_size == 0
+    heads_ok = tp_size <= 1 or cfg.n_kv_heads % tp_size == 0
     if batch % max(dp_n, 1) == 0 and batch >= dp_n:
         return (DP, None, TP, None) if heads_ok else (DP, TP, None, None)
     # tiny batch (long-context decode): shard the sequence dim
@@ -116,12 +123,13 @@ def attn_apply(
     window: Optional[int] = None,
     causal: bool = True,
     positions: Optional[jnp.ndarray] = None,  # (S,) or per-row (B, S)
-    cache: Optional[Dict[str, jnp.ndarray]] = None,
+    cache: Optional[LayerCache] = None,  # this layer's place in the stage's {k, v}
     cache_len: Optional[jnp.ndarray] = None,  # scalar or per-row (B,) int32
     cross_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # encoder k, v
     use_rope: bool = True,
-) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
-    """Returns (out, updated_cache)."""
+) -> Tuple[jnp.ndarray, Optional[LayerCache]]:
+    """Returns (out, cache): `cache` is the handle after this layer wrote
+    its new rows, None without a cache."""
     B, S, D = x.shape
     tp = _tp_size()
     scale = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(cfg.hd)
@@ -158,35 +166,26 @@ def attn_apply(
         return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
 
     spec = cache_logical_spec(cfg, tp, B)
-    # seq-dim sharded caches cannot take dynamic_update_slice at a traced
-    # index (SPMD would all-gather the cache); use local masked writes
-    dus_ok = spec[1] is None
     if S > 1:
         # lay fresh k/v out like the cache BEFORE the update — otherwise SPMD
-        # falls back to replicate-then-repartition around dynamic_update_slice
+        # falls back to replicate-then-repartition around the write
         k = shard(k, *spec)
         v = shard(v, *spec)
+    cache = cache.write({"k": k, "v": v}, cache_len, spec=spec)
     if S == 1:
-        # decode: append then attend against cache
-        new_k = write_row(cache["k"], k, cache_len, dus_ok=dus_ok)
-        new_v = write_row(cache["v"], v, cache_len, dus_ok=dus_ok)
-        new_k = shard(new_k, *spec)
-        new_v = shard(new_v, *spec)
+        # decode: attend against the layer's cache, the new row included
+        kv = cache.read()
         out = ops.decode_attention(
             q[:, 0],
-            new_k,
-            new_v,
+            shard(kv["k"], *spec),
+            shard(kv["v"], *spec),
             jnp.broadcast_to(jnp.atleast_1d(cache_len) + 1, (B,)).astype(jnp.int32),
             logit_cap=cfg.attn_softcap,
             window=window,
             scale=scale,
         )[:, None]  # (B, 1, H, hd)
     else:
-        # prefill: write the whole segment, attend causally within it
-        new_k = write_segment(cache["k"], k, cache_len, dus_ok=dus_ok)
-        new_v = write_segment(cache["v"], v, cache_len, dus_ok=dus_ok)
-        new_k = shard(new_k, *spec)
-        new_v = shard(new_v, *spec)
+        # prefill: attend causally within the segment just written
         out = ops.flash_attention(
             q, k, v,
             causal=causal,
@@ -196,7 +195,7 @@ def attn_apply(
             scale=scale,
         )
     out = shard(out, DP, None, TP, None)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), {"k": new_k, "v": new_v}
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 
 
 def cross_kv_init(p: Params, enc_out: jnp.ndarray, cfg: ModelConfig):

@@ -14,13 +14,15 @@ heads.  Two execution forms:
 
 Cache sharding: (B, S, r) latent is head-free, so the sequence dim shards
 over the model axis (the decode softmax reductions become all-reduces —
-flash-decoding via SPMD).
+flash-decoding via SPMD).  The stage owns the stacked latent cache; the
+layer writes its rows through its `cache_update.LayerCache`, which picks
+the masked write only where that model axis really splits the sequence.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +30,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 
-from .cache_update import write_row, write_segment
+from .cache_update import LayerCache
 from .layers import Params, apply_rope, dense_init, rmsnorm, rmsnorm_init
 from .sharding import DP, TP, shard
 
@@ -90,9 +92,11 @@ def mla_apply(
     cfg: ModelConfig,
     *,
     positions: Optional[jnp.ndarray] = None,
-    cache: Optional[Dict[str, jnp.ndarray]] = None,
+    cache: Optional[LayerCache] = None,  # this layer's place in the stage's {c_kv, k_pe}
     cache_len: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
+) -> Tuple[jnp.ndarray, Optional[LayerCache]]:
+    """Returns (out, cache): `cache` is the handle after this layer wrote
+    its latent rows, None without a cache."""
     B, S, D = x.shape
     m = cfg.mla
     scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
@@ -104,11 +108,10 @@ def mla_apply(
 
     if cache is not None and S == 1:
         # ---- absorbed decode ------------------------------------------
-        # latent cache is sequence-sharded: masked write, never DUS
-        new_ckv = write_row(cache["c_kv"], c_kv, cache_len, dus_ok=False)
-        new_kpe = write_row(cache["k_pe"], k_pe, cache_len, dus_ok=False)
-        new_ckv = shard(new_ckv, *mla_cache_spec())
-        new_kpe = shard(new_kpe, *mla_cache_spec())
+        cache = cache.write({"c_kv": c_kv, "k_pe": k_pe}, cache_len, spec=mla_cache_spec())
+        lat = cache.read()
+        new_ckv = shard(lat["c_kv"], *mla_cache_spec())
+        new_kpe = shard(lat["k_pe"], *mla_cache_spec())
 
         kv_up_k = p["kv_up"][..., : m.nope_head_dim]  # (r, H, nope)
         kv_up_v = p["kv_up"][..., m.nope_head_dim :]  # (r, H, v)
@@ -127,7 +130,7 @@ def mla_apply(
         ctx_lat = jnp.einsum("bhs,bsr->bhr", probs, new_ckv.astype(jnp.float32))
         ctx = jnp.einsum("bhr,rhv->bhv", ctx_lat, kv_up_v.astype(jnp.float32))
         out = jnp.einsum("bhv,hvd->bd", ctx.astype(x.dtype), p["wo"])[:, None]
-        return out, {"c_kv": new_ckv, "k_pe": new_kpe}
+        return out, cache
 
     # ---- train / prefill: materialize per-head K and V ------------------
     kv = jnp.einsum("bsr,rhk->bshk", c_kv, p["kv_up"])
@@ -145,12 +148,6 @@ def mla_apply(
     out = shard(out, DP, None, TP, None)
     y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
 
-    new_cache = None
     if cache is not None:
-        new_ckv = write_segment(cache["c_kv"], c_kv, cache_len, dus_ok=False)
-        new_kpe = write_segment(cache["k_pe"], k_pe, cache_len, dus_ok=False)
-        new_cache = {
-            "c_kv": shard(new_ckv, *mla_cache_spec()),
-            "k_pe": shard(new_kpe, *mla_cache_spec()),
-        }
-    return y, new_cache
+        cache = cache.write({"c_kv": c_kv, "k_pe": k_pe}, cache_len, spec=mla_cache_spec())
+    return y, cache

@@ -13,6 +13,7 @@ data axes.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, Optional, Tuple
 
@@ -74,6 +75,19 @@ def physical_axes(mesh: Mesh, logical):
         return "model" if "model" in names else None
     # literal mesh axis name passthrough
     return logical if logical in names else None
+
+
+def devices_along(logical) -> int:
+    """How many devices the ambient mesh splits a dimension over when its
+    logical spec is `logical` (1 without a mesh, or where the axes it maps
+    to all have size 1)."""
+    mesh = current_mesh()
+    if mesh is None:
+        return 1
+    ax = physical_axes(mesh, logical)
+    if ax is None:
+        return 1
+    return math.prod(mesh.shape[a] for a in (ax if isinstance(ax, tuple) else (ax,)))
 
 
 def make_pspec(mesh: Mesh, *logical) -> P:

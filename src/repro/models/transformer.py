@@ -10,6 +10,9 @@ Each stage function has signature
     stage_apply(params, h, cfg, mode, cache, cache_len, ...)
       -> (h, new_cache, aux_losses)
 where cache is the stage's stacked cache pytree (or None in train mode).
+Attention K/V stacks ride in the scan's carry and each layer writes its new
+rows into them in place through a `cache_update.LayerCache`; recurrent
+states, replaced whole every step, are scanned inputs and outputs.
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ from . import mamba2 as mb
 from . import mla as mla_mod
 from . import moe as moe_mod
 from . import xlstm as xl
+from .cache_update import LayerCache
 from .layers import Params, mlp_apply, mlp_init, rmsnorm, rmsnorm_init, scan_unroll
 from .sharding import residual_shard, shard
 
@@ -81,10 +85,10 @@ def decoder_layer_apply(
     *,
     window: Optional[int],
     positions: jnp.ndarray,
-    cache: Optional[Dict[str, jnp.ndarray]],
+    cache: Optional[LayerCache],
     cache_len: Optional[jnp.ndarray],
     use_moe: bool,
-) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
+) -> Tuple[jnp.ndarray, Optional[LayerCache], jnp.ndarray]:
     h = residual_shard(h)
     x = rmsnorm(h, p["ln1"], eps=cfg.rms_eps)
     if cfg.mla is not None:
@@ -141,39 +145,51 @@ def decoder_stage_apply(
     use_moe: bool,
     remat: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
+    """Scan the stage's layers, (outer, period) stacked, period unrolled.
+
+    The stage owns the cache: the stacked (outer, period, B, S, ...) leaves
+    ride in the scan's carry, and layer [l, i] gets a
+    `cache_update.LayerCache` on them.  The layer hands it the rows it
+    produced (one per slot in decode, the segment in prefill), which are
+    written at [l, i, b, cache_len[b]] (or at the scalar `cache_len`) and
+    nowhere else; the stage takes the updated stack back.  Returns
+    (h, stack, aux) — stack None without a cache (training)."""
     period = cfg.global_every if (cfg.sliding_window and cfg.global_every) else 1
 
     def body(carry, xs):
-        hh, aux = carry
-        layer_params, layer_cache = xs
-        new_caches = []
+        hh, aux, stack = carry
+        layer_params, l = xs
         for i in range(period):
             pi = jax.tree_util.tree_map(lambda a, i=i: a[i], layer_params)
-            ci = None if layer_cache is None else jax.tree_util.tree_map(lambda a, i=i: a[i], layer_cache)
+            ci = None if stack is None else LayerCache(stack, (l, i))
             window = None
             if cfg.sliding_window and period > 1 and i < period - 1:
                 window = cfg.sliding_window
             elif cfg.sliding_window and period == 1:
                 window = cfg.sliding_window
-            hh, nc, a = decoder_layer_apply(
+            hh, ci, a = decoder_layer_apply(
                 pi, hh, cfg,
                 window=window, positions=positions,
                 cache=ci, cache_len=cache_len, use_moe=use_moe,
             )
             aux = aux + a
-            new_caches.append(nc)
-        nc_stacked = (
-            None
-            if new_caches[0] is None
-            else jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *new_caches)
-        )
-        return (hh, aux), nc_stacked
+            stack = None if ci is None else ci.stack
+        return (hh, aux, stack), None
 
     body = _remat(body, remat)
-    (h, aux), new_cache = jax.lax.scan(
-        body, (h, jnp.zeros((), jnp.float32)), (params, cache), unroll=scan_unroll()
+    (h, aux, new_cache), _ = jax.lax.scan(
+        body, (h, jnp.zeros((), jnp.float32), cache), (params, _layer_index(cache, params)),
+        unroll=scan_unroll(),
     )
     return h, new_cache, aux
+
+
+def _layer_index(cache: Optional[Dict], params: Params) -> Optional[jnp.ndarray]:
+    """Scan input giving each iteration its index on the stacked cache's
+    first axis; None without a cache, leaving the training scan as it is."""
+    if cache is None:
+        return None
+    return jnp.arange(jax.tree_util.tree_leaves(params)[0].shape[0], dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -240,35 +256,44 @@ def xdecoder_stage_apply(
     cache_len: Optional[jnp.ndarray] = None,
     remat: bool = False,
 ):
-    """cache: {"self": {k,v}, "cross": {k,v}} stacked (L, ...)."""
+    """cache: {"self": {k,v}, "cross": {k,v}} stacked (L, ...).
+
+    The self-attention stack rides in the carry and is written in place;
+    the cross K/V, computed once from `enc_out` (prefill), is read-only
+    after that and passes through untouched."""
+    cross = None if cache is None else cache.get("cross")
 
     def body(carry, xs):
-        hh = carry
-        layer, layer_cache = xs
+        hh, stack = carry
+        layer, layer_cross, l = xs
         x = rmsnorm(hh, layer["ln1"], eps=cfg.rms_eps)
-        self_cache = None if layer_cache is None else layer_cache["self"]
-        a, new_self = attn.attn_apply(
+        a, lc = attn.attn_apply(
             layer["self_attn"], x, cfg,
-            positions=positions, cache=self_cache, cache_len=cache_len,
-            use_rope=False,
+            positions=positions, cache=None if stack is None else LayerCache(stack, (l,)),
+            cache_len=cache_len, use_rope=False,
         )
         hh = hh + a
         x = rmsnorm(hh, layer["ln_x"], eps=cfg.rms_eps)
-        if layer_cache is not None and "cross" in layer_cache:
-            ck, cv = layer_cache["cross"]["k"], layer_cache["cross"]["v"]
+        if layer_cross is not None:
+            ck, cv = layer_cross["k"], layer_cross["v"]
         else:
             ck, cv = attn.cross_kv_init(layer["cross_attn"], enc_out, cfg)
         a, _ = attn.attn_apply(layer["cross_attn"], x, cfg, cross_kv=(ck, cv))
         hh = hh + a
         x = rmsnorm(hh, layer["ln2"], eps=cfg.rms_eps)
         hh = hh + mlp_apply(layer["mlp"], x, cfg.act)
-        new_cache = None
-        if layer_cache is not None:
-            new_cache = {"self": new_self, "cross": {"k": ck, "v": cv}}
-        return hh, new_cache
+        new_cross = None if stack is None or layer_cross is not None else {"k": ck, "v": cv}
+        return (hh, None if lc is None else lc.stack), new_cross
 
     body = _remat(body, remat)
-    h, new_cache = jax.lax.scan(body, h, (params, cache), unroll=scan_unroll())
+    self_cache = None if cache is None else cache["self"]
+    (h, new_self), new_cross = jax.lax.scan(
+        body, (h, self_cache), (params, cross, _layer_index(cache, params)),
+        unroll=scan_unroll(),
+    )
+    new_cache = None
+    if cache is not None:
+        new_cache = {"self": new_self, "cross": new_cross if cross is None else cross}
     return h, new_cache
 
 
@@ -300,7 +325,7 @@ def shared_attn_block_apply(
     cfg: ModelConfig,
     *,
     positions: jnp.ndarray,
-    cache: Optional[Dict] = None,
+    cache: Optional[LayerCache] = None,
     cache_len: Optional[jnp.ndarray] = None,
 ):
     xcat = jnp.concatenate([h, h0], axis=-1)  # (B, S, 2D)
@@ -348,36 +373,41 @@ def hybrid_stage_apply(
     h0 = h  # embeddings for the concat trick
 
     def body(carry, xs):
-        hh = carry
-        layer_params, layer_cache = xs
+        hh, attn_stack = carry
+        layer_params, layer_states, l = xs
         mstates = []
         for i in range(per):
             pi = jax.tree_util.tree_map(lambda a, i=i: a[i], layer_params["mamba"])
             si = (
                 None
-                if layer_cache is None
-                else jax.tree_util.tree_map(lambda a, i=i: a[i], layer_cache["mamba"])
+                if layer_states is None
+                else jax.tree_util.tree_map(lambda a, i=i: a[i], layer_states)
             )
             out, ns = mb.mamba2_apply(pi, hh, cfg, state=si)
             hh = hh + out
             mstates.append(ns)
-        attn_cache = None if layer_cache is None else layer_cache["attn"]
-        hh, new_attn = shared_attn_block_apply(
+        hh, lc = shared_attn_block_apply(
             params["shared"], hh, h0, cfg,
-            positions=positions, cache=attn_cache, cache_len=cache_len,
+            positions=positions,
+            cache=None if attn_stack is None else LayerCache(attn_stack, (l,)),
+            cache_len=cache_len,
         )
-        new_cache = None
-        if layer_cache is not None:
-            new_cache = {
-                "mamba": jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *mstates),
-                "attn": new_attn,
-            }
-        return hh, new_cache
+        new_states = None
+        if layer_states is not None:
+            new_states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *mstates)
+        return (hh, None if lc is None else lc.stack), new_states
 
     body = _remat(body, remat)
-    super_xs_cache = None if cache is None else cache["super"]
-    h, new_super = jax.lax.scan(
-        body, h, ({"mamba": params["super"]}, super_xs_cache), unroll=scan_unroll()
+    super_cache = None if cache is None else cache["super"]
+    (h, new_attn), new_mamba = jax.lax.scan(
+        body,
+        (h, None if super_cache is None else super_cache["attn"]),
+        (
+            {"mamba": params["super"]},
+            None if super_cache is None else super_cache["mamba"],
+            _layer_index(cache, params["super"]),
+        ),
+        unroll=scan_unroll(),
     )
 
     new_tail = None
@@ -396,7 +426,7 @@ def hybrid_stage_apply(
 
     new_cache = None
     if cache is not None:
-        new_cache = {"super": new_super, "tail": new_tail}
+        new_cache = {"super": {"mamba": new_mamba, "attn": new_attn}, "tail": new_tail}
     return h, new_cache
 
 

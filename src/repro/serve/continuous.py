@@ -112,13 +112,16 @@ class ContinuousEngine:
                 lambda b, s, ax: insert_rows(b, s, slots, ax), big, small, axes
             )
 
-        self._decode = jax.jit(decode)
-        self._prefill = jax.jit(prefill)
+        # caches are donated: a decode step writes each slot's new rows into
+        # the persistent cache in place, an insert only the slots it fills,
+        # and a prefill its prompt's rows into the fresh one-row cache
+        self._decode = jax.jit(decode, donate_argnums=2)
+        self._prefill = jax.jit(prefill, donate_argnums=2)
         # caches are built in place on the device (no host or device-0 copy)
         self._new_cache = jax.jit(
             new_cache, static_argnums=0, out_shardings=SingleDeviceSharding(self.device)
         )
-        self._insert = jax.jit(insert)
+        self._insert = jax.jit(insert, donate_argnums=0)
         self.engine_id: Optional[str] = None  # set by `run`; tags spans
 
         B = scfg.max_batch

@@ -79,7 +79,7 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.scfg = scfg
-        self._decode = jax.jit(lambda p, t, c, l: decode_step(p, cfg, t, c, l))
+        self._decode = jax.jit(lambda p, t, c, l: decode_step(p, cfg, t, c, l), donate_argnums=2)
         self._prefill = jax.jit(lambda p, b, c: prefill(p, cfg, b, c))
 
     # ---- batch generation ------------------------------------------------

@@ -65,55 +65,101 @@ def test_smoke_train_step(arch):
     assert not np.allclose(before, after)
 
 
+def _prefill_rows(params, cfg, batch_full, plens, max_len):
+    """Prefill each row alone at its own prompt length and stack the rows'
+    caches along each leaf's batch axis (found by tracing prefill at batch
+    sizes 1 and 2): the rows then stand at different positions, as slots
+    of a continuous batch do.  Returns (cache, (B,) cache_len)."""
+
+    def row_batch(b, n):
+        batch = {k: v[b : b + 1] for k, v in batch_full.items()}
+        batch["tokens"] = batch["tokens"][:, :n]
+        return batch
+
+    def cache_shape(nb):
+        batch = {k: jnp.concatenate([v] * nb) for k, v in row_batch(0, plens[0]).items()}
+        cache = init_cache(cfg, nb, max_len=max_len, cache_dtype=jnp.float32)
+        return jax.eval_shape(lambda: prefill(params, cfg, batch, cache)[1])
+
+    axes = jax.tree_util.tree_map(
+        lambda a, b: next(i for i, (m, n) in enumerate(zip(a.shape, b.shape)) if m != n),
+        cache_shape(1), cache_shape(2),
+    )
+    caches, lens = [], []
+    for b, n in enumerate(plens):
+        cache = init_cache(cfg, 1, max_len=max_len, cache_dtype=jnp.float32)
+        _, cache, clen = prefill(params, cfg, row_batch(b, n), cache)
+        caches.append(cache)
+        lens.append(int(clen))
+    cache = jax.tree_util.tree_map(lambda ax, *xs: jnp.concatenate(xs, ax), axes, *caches)
+    return cache, jnp.asarray(lens, jnp.int32)
+
+
+def _decode_against_forward(arch, per_row, n_dec=3):
+    """Prefill, then decode `n_dec` tokens teacher-forced from one sequence
+    per row; returns [(decode logits (B, V), full-forward logits (B, V))]
+    for the prefill's last token and each decoded one.
+
+    `per_row` False: both rows prefill 12 tokens together and decode with a
+    scalar cache_len.  True: rows prefill 5 and 12 tokens alone and decode
+    with a (B,) cache_len; the longer row's last write lands at max_len - 2,
+    the last position a continuous-batching slot writes."""
+    cfg = CONFIGS[arch].reduced()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prefix = cfg.num_prefix_tokens if cfg.frontend == "vision_stub" else 0
+    plens = (5, 12) if per_row else (12, 12)
+    batch_full = _batch_for(cfg, len(plens), max(plens) + n_dec, seed=1)
+    logits_full, _, _ = forward(params, cfg, batch_full)
+    rows = np.arange(len(plens))
+
+    def full_at(t):  # forward logits at each row's t-th token past its prompt
+        return logits_full[rows, prefix + np.asarray(plens) + t]
+
+    if per_row:
+        max_len = prefix + max(plens) + n_dec + 1
+        cache, clen = _prefill_rows(params, cfg, batch_full, plens, max_len)
+        lg = None
+    else:
+        cache = init_cache(cfg, 2, max_len=prefix + 12 + n_dec + 4, cache_dtype=jnp.float32)
+        batch_pre = dict(batch_full)
+        batch_pre["tokens"] = batch_full["tokens"][:, :12]
+        lg, cache, clen = prefill(params, cfg, batch_pre, cache)
+        lg = lg[:, -1]
+    pairs = [] if lg is None else [(lg, full_at(-1))]
+    for t in range(n_dec):
+        tok = batch_full["tokens"][rows, np.asarray(plens) + t][:, None]
+        lg, cache = decode_step(params, cfg, tok, cache, clen)
+        clen = clen + 1
+        pairs.append((lg[:, 0], full_at(t)))
+    if per_row:
+        assert int(clen.max()) - 1 == max_len - 2
+    return pairs
+
+
+CACHE_LEN_FORMS = pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+
+
+@CACHE_LEN_FORMS
 @pytest.mark.parametrize(
     "arch",
     [a for a in ARCHS if CONFIGS[a].moe is None],  # MoE: capacity drops differ
 )
-def test_decode_matches_forward_exactly(arch):
-    cfg = CONFIGS[arch].reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    B, S_prompt, n_dec = 2, 12, 3
-    total = S_prompt + n_dec
-    batch_full = _batch_for(cfg, B, total, seed=1)
-    logits_full, _, _ = forward(params, cfg, batch_full)
-    prefix = cfg.num_prefix_tokens if cfg.frontend == "vision_stub" else 0
-
-    cache = init_cache(cfg, B, max_len=total + prefix + 4, cache_dtype=jnp.float32)
-    batch_pre = dict(batch_full)
-    batch_pre["tokens"] = batch_full["tokens"][:, :S_prompt]
-    lg, cache, clen = prefill(params, cfg, batch_pre, cache)
-    np.testing.assert_allclose(
-        lg[:, -1], logits_full[:, prefix + S_prompt - 1], atol=2e-3, rtol=1e-3
-    )
-    for t in range(n_dec):
-        lg, cache = decode_step(
-            params, cfg, batch_full["tokens"][:, S_prompt + t][:, None], cache, clen
-        )
-        clen = clen + 1
-        np.testing.assert_allclose(
-            lg[:, 0], logits_full[:, prefix + S_prompt + t], atol=2e-3, rtol=1e-3
-        )
+def test_decode_matches_forward_exactly(arch, per_row):
+    for got, want in _decode_against_forward(arch, per_row):
+        np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
 
 
+@CACHE_LEN_FORMS
 @pytest.mark.parametrize("arch", [a for a in ARCHS if CONFIGS[a].moe is not None])
-def test_decode_close_for_moe(arch):
+def test_decode_close_for_moe(arch, per_row):
     """Capacity-based MoE may drop different tokens at different batch
     compositions (known train/serve property); require closeness only."""
-    cfg = CONFIGS[arch].reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    B, S_prompt = 2, 12
-    batch_full = _batch_for(cfg, B, S_prompt + 1, seed=1)
-    logits_full, _, _ = forward(params, cfg, batch_full)
-    cache = init_cache(cfg, B, max_len=S_prompt + 8, cache_dtype=jnp.float32)
-    batch_pre = dict(batch_full)
-    batch_pre["tokens"] = batch_full["tokens"][:, :S_prompt]
-    lg, cache, clen = prefill(params, cfg, batch_pre, cache)
-    # rank correlation of top prediction rather than exact equality
-    top_full = np.asarray(jnp.argmax(logits_full[:, S_prompt - 1], -1))
-    top_dec = np.asarray(jnp.argmax(lg[:, -1], -1))
-    assert (top_full == top_dec).mean() >= 0.5
-    err = float(jnp.max(jnp.abs(lg[:, -1] - logits_full[:, S_prompt - 1])))
-    assert err < 0.2
+    for got, want in _decode_against_forward(arch, per_row):
+        # rank correlation of top prediction rather than exact equality
+        top_full = np.asarray(jnp.argmax(want, -1))
+        top_dec = np.asarray(jnp.argmax(got, -1))
+        assert (top_full == top_dec).mean() >= 0.5
+        assert float(jnp.max(jnp.abs(got - want))) < 0.2
 
 
 def test_param_counts_match_published_sizes():

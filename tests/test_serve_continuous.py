@@ -15,6 +15,7 @@ The pins, straight from the PR contract:
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -57,6 +58,39 @@ def _prompts(cfg, lens, seed=0):
 # ---------------------------------------------------------------------------
 # slot engine semantics (no request plane)
 # ---------------------------------------------------------------------------
+
+def _aliased_params(compiled):
+    """Parameter numbers the compiled program writes its outputs into."""
+    head = compiled.as_text().split("\n", 1)[0]
+    return {int(n) for n in re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", head)}
+
+
+def test_decode_and_insert_update_the_cache_in_place():
+    """The persistent cache is donated to `jit_decode` and `jit_insert`
+    (each aliases it to its output), and a decode step writes each slot's
+    row with a scatter, never with a masked rewrite: no `select` in the
+    optimised program is shaped like a layer's K/V cache.  `jit_prefill`
+    writes into the fresh one-row cache it is given the same way."""
+    cfg, params, scfg = _setup()
+    eng = ContinuousEngine(cfg, params, scfg)
+    B = scfg.max_batch
+    cache_params = len(jax.tree_util.tree_leaves(eng.cache))
+    toks = eng._put(np.zeros((B, 1), np.int32))
+    dec = eng._decode.lower(
+        eng.params, toks, eng.cache, eng._put(np.zeros((B,), np.int32))
+    ).compile()
+    first = len(jax.tree_util.tree_leaves((eng.params, toks)))
+    assert _aliased_params(dec) == set(range(first, first + cache_params))
+    layer = ",".join(map(str, eng.cache["decoder"]["k"].shape[2:]))  # B,S,K,hd
+    selects = [ln for ln in dec.as_text().splitlines() if " select(" in ln and f"{layer}]" in ln]
+    assert not selects, selects
+    ins = eng._insert.lower(eng.cache, eng._new_cache(1), eng._put(np.asarray([0]))).compile()
+    assert _aliased_params(ins) == set(range(cache_params))
+    batch = {"tokens": eng._put(np.zeros((1, scfg.prefill_bucket), np.int32))}
+    pre = eng._prefill.lower(eng.params, batch, eng._new_cache(1)).compile()
+    first = len(jax.tree_util.tree_leaves((eng.params, batch)))
+    assert _aliased_params(pre) == set(range(first, first + cache_params))
+
 
 def test_mid_stream_admission_without_draining():
     """A request admitted at a chunk boundary joins slots that are mid-

@@ -10,6 +10,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -107,10 +109,16 @@ def test_tiny_mesh_train_lowering_with_collectives():
     assert "OK" in out
 
 
-def test_tiny_mesh_decode_lowering():
+@pytest.mark.parametrize("n_kv_heads", [4, 2], ids=["heads_split", "seq_split"])
+def test_tiny_mesh_decode_lowering(n_kv_heads):
+    """Lower decode on an 8-device mesh with a scalar and a per-slot (B,)
+    cache_len.  4 KV heads split over tp=4 (the sequence axis whole: rows
+    written in place); 2 do not, so the sequence axis splits over tp
+    (masked writes).  Either way no all-gather of a cache layer, whose
+    shape ends in (S=128, K, hd=32), appears."""
     out = run_sub(
-        """
-        import jax, jax.numpy as jnp, dataclasses
+        f"""
+        import re, jax, jax.numpy as jnp, dataclasses
         from repro.configs import CONFIGS
         from repro.launch.mesh import make_mesh
         from repro.launch.shardings import cache_pspec, state_pspec, to_shardings
@@ -118,7 +126,7 @@ def test_tiny_mesh_decode_lowering():
 
         cfg = dataclasses.replace(
             CONFIGS["qwen3-32b"].reduced(),
-            d_model=256, n_heads=8, n_kv_heads=4, head_dim=32, d_ff=512,
+            d_model=256, n_heads=8, n_kv_heads={n_kv_heads}, head_dim=32, d_ff=512,
             vocab_size=512, n_layers=2,
         )
         mesh = make_mesh(dp=2, tp=4)
@@ -133,14 +141,20 @@ def test_tiny_mesh_decode_lowering():
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             cache_shapes, csh)
         fn = lambda p, t, c, l: decode_step(p, cfg, t, c, l)
-        with jax.set_mesh(mesh):
-            compiled = jax.jit(fn, donate_argnums=(2,)).lower(
-                params_structs,
-                jax.ShapeDtypeStruct((8, 1), jnp.int32),
-                cache_structs,
-                jax.ShapeDtypeStruct((), jnp.int32),
-            ).compile()
-        assert compiled.memory_analysis().argument_size_in_bytes > 0
+        for clen in ((), (8,)):
+            with jax.set_mesh(mesh):
+                compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+                    params_structs,
+                    jax.ShapeDtypeStruct((8, 1), jnp.int32),
+                    cache_structs,
+                    jax.ShapeDtypeStruct(clen, jnp.int32),
+                ).compile()
+            assert compiled.memory_analysis().argument_size_in_bytes > 0
+            gathers = [
+                ln for ln in compiled.as_text().splitlines()
+                if re.search(r"\\[(?:\\d+,)*128,\\d+,32\\]\\S* all-gather\\(", ln)
+            ]
+            assert not gathers, (clen, gathers)
         print("OK")
         """
     )
