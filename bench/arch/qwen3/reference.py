@@ -18,8 +18,8 @@ none in the equations; the weights are random (`weights.py`) and the
 computation is float32 with `default_matmul_precision("highest")`.
 
 `quant` computes the same pass in a lower precision, as the control of
-the comparison: weights rounded to int8 (symmetric, one scale per output
-column) or to float8 e4m3, activations and products in bfloat16.
+the comparison: weights rounded to int8 or to float8 e4m3
+(`bench/lib/weights.quantize`), activations and products in bfloat16.
 """
 
 from __future__ import annotations
@@ -31,26 +31,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.lib.weights import quantize
+
 from .weights import dims, head
 
 Q_BLOCK = 256  # query rows per attention block (bounds the score matrix)
 SEQ_MULTIPLE = 512  # sequences pad to this, so that few shapes compile
 V_BLOCKS = 8  # vocabulary blocks of the output head
-
-
-def _quantize(w: jax.Array, quant: str) -> jax.Array:
-    """Round a (..., in, out) matrix as a lower-precision server would."""
-    wf = w.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(wf), axis=-2, keepdims=True)
-    if quant == "int8":
-        scale = jnp.maximum(amax, 1e-30) / 127.0
-        return (jnp.round(wf / scale) * scale).astype(jnp.bfloat16)
-    if quant == "fp8":
-        scale = jnp.maximum(amax, 1e-30) / 448.0
-        return ((wf / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale).astype(
-            jnp.bfloat16
-        )
-    raise ValueError(f"unknown quant {quant!r}")
 
 
 def _rms(x, g, eps):
@@ -79,7 +66,7 @@ def _layer(h, lw, *, config, pos, act_dtype, quant):
 
     def mat(name):
         w = lw[name]
-        return _quantize(w, quant) if quant else w.astype(act_dtype)
+        return quantize(w, quant) if quant else w.astype(act_dtype)
 
     def mm(x, w):
         return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(act_dtype)
@@ -141,7 +128,7 @@ def _logits(w, hn, rows, *, quant):
     def one(wb):
         if quant:
             return jnp.dot(
-                x.astype(jnp.bfloat16), _quantize(wb, quant),
+                x.astype(jnp.bfloat16), quantize(wb, quant),
                 preferred_element_type=jnp.float32,
             )
         return jnp.dot(x.astype(jnp.float32), wb.astype(jnp.float32))

@@ -36,7 +36,7 @@ def main() -> int:
     ap.add_argument("--traffic", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=20.0)
-    ap.add_argument("--control", default=None, help="a precision of reference.py: int8, fp8")
+    ap.add_argument("--control", default=None, help="a precision of the reference's logits_at: int8, fp8")
     ap.add_argument("--fault", default=None, help="a fault of bench/lib/faults.py")
     args = ap.parse_args()
     sys.path.insert(0, ROOT)

@@ -12,15 +12,17 @@ Once the window has closed and the engine's state is freed:
   mid_batch     (open loop) requests admitted into a running batch
   logit_gap     a sample of finished requests drawn from the seed, with the
                 longest among them, is run through the float32 reference
-                (`reference.py`) over prompt + served tokens; the number is
-                the widest gap by which a served token's reference logit
-                lies below the reference's best at that position
+                (the binding's `logits_at`, `bench/arch/`) over prompt +
+                served tokens; the number is the widest gap by which a
+                served token's reference logit lies below the reference's
+                best at that position
 
 The limit of `logit_gap` is the configuration's `limits.logit_gap`, set
 between the program's readings over a dozen seeds and the control's, as
 `PERF.md` records.  The control is the same reference with float8 weights
-(`reference.py`) put in the program's place: a run with `control` compares
-the tokens it puts first, and its `correct` has to come out false.
+(`logits_at(..., quant="fp8")`) put in the program's place: a run with
+`control` compares the tokens it puts first, and its `correct` has to come
+out false.
 """
 
 from __future__ import annotations
@@ -30,7 +32,9 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference, system
+from bench import arch
+
+from . import system
 
 
 def sample(outcomes, seed: int, min_tokens: int, max_requests: int) -> List[Any]:
@@ -55,16 +59,17 @@ def logit_gaps(w, config, picked, *, quant=None) -> List[float]:
     """Per request, the widest gap of its served tokens below the float32
     reference's best logit.  With `quant`, the served tokens are the ones
     the lower-precision reference puts first (the control)."""
+    ref_logits = arch.for_config(config).logits_at
     gaps = []
     for o in picked:
         prompt, out = o.req.prompt, o.tokens
         seq = list(prompt) + list(out[:-1])
         rows = np.arange(len(prompt) - 1, len(seq))
-        ref = np.asarray(reference.logits_at(w, config, seq, rows))
+        ref = np.asarray(ref_logits(w, config, seq, rows))
         if quant is None:
             served = np.asarray(out)
         else:
-            low = np.asarray(reference.logits_at(w, config, seq, rows, quant=quant))
+            low = np.asarray(ref_logits(w, config, seq, rows, quant=quant))
             served = low.argmax(-1)
         best = ref.max(-1)
         gaps.append(float((best - ref[np.arange(len(rows)), served]).max()))

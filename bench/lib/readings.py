@@ -15,7 +15,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Tuple
 
-from . import cost
+from bench import arch
+
 from .trace import Program, Reduced
 
 
@@ -54,14 +55,16 @@ def decode_chunks(red: Reduced) -> List[Tuple[List[Program], List[int]]]:
 
 def model_flops(red: Reduced, config: Dict[str, Any]) -> float:
     """Model FLOPs of the whole calls inside the traced window: every
-    prompt prefilled and every token decoded (see `cost.py`)."""
+    prompt prefilled and every token decoded, as the configuration's
+    binding counts them (`bench/arch/`)."""
+    b = arch.for_config(config)
     lo, hi = red.window
     total = 0.0
     for (n, s, e), info in zip(red.host, red.host_info):
         if info is None or s < lo or e > hi:
             continue
         if n == "admit":
-            total += sum(cost.prefill_flops(config, k) for _, k in info)
+            total += sum(b.prefill_flops(config, k) for _, k in info)
         elif n == "step_chunk":
-            total += sum(cost.decode_token_flops(config, c) for c in info["ctx"])
+            total += sum(b.decode_token_flops(config, c) for c in info["ctx"])
     return total

@@ -343,7 +343,7 @@ def run_cell(
 ) -> Dict[str, Any]:
     """Run `cell` once on `device`: build and warm, offer the mix, then
     decide `correct` with the engine freed.  With `control` (a precision
-    of `reference.py`), the reference in that precision stands in for the
+    of the binding's `logits_at`), the reference in that precision stands in for the
     served tokens in the comparison, and `correct` has to come out false."""
     clock = CompileClock()
     engine = prepare(cell, seed, device)

@@ -4,12 +4,17 @@
 Each piece lives in a file of its own, found by its name alone:
 
   bench/configs/<config>.json    sizes, engine settings, limits of `correct`
+  bench/arch/<model_type>[.py]   the architecture binding that a config's
+                                 `model_type` names: its weights, plain
+                                 reference, parameter layout and op counts
+                                 (the interface: `bench/arch/__init__.py`)
   bench/traffic/<traffic>.json   parameters of the one general generator
   bench/metrics/<metric>.py      a reader with `read(run) -> float | None`
   bench/peaks.json               peak rates keyed by `device_kind`
 
-A later change adds a configuration, a mix or a per-layer metric by adding
-such a file and an entry in `BENCHMARK.json`; no existing file changes.
+A later change adds a configuration (of a new architecture too), a mix or
+a per-layer metric by adding such files and an entry in `BENCHMARK.json`;
+no existing file changes.
 """
 
 from __future__ import annotations

@@ -1,9 +1,11 @@
 """The benchmark's binding to the system under test.
 
-Everything the benchmark knows of the program is here: how a configuration
-file becomes a `ModelConfig` and a `ServeConfig`, how the benchmark's own
-weights are laid out as the program's parameters, how an engine is built
-and warmed, and which calls of the serving loop carry spans.
+Everything the benchmark knows of the serving program is here: how a
+configuration file becomes a `ServeConfig`, how an engine is built from
+the benchmark's own weights and warmed, and which calls of the serving
+loop carry spans.  What depends on the model's architecture (its
+`ModelConfig`, weights and parameter layout) is its binding's, found by
+the configuration's `model_type` (`bench/arch/`).
 """
 
 from __future__ import annotations
@@ -14,36 +16,14 @@ import time
 from typing import Any, Dict, Iterable, List
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.base import ModelConfig
+from bench import arch
 from repro.models import init_params
 from repro.serve import ContinuousEngine, ServeConfig
 from repro.serve import request_plane as rp
 
-from .weights import dims, make_weights, seed_key
-
-
-def model_config(config: Dict[str, Any]) -> ModelConfig:
-    d = dims(config)
-    return ModelConfig(
-        name=config["name"],
-        family="dense",
-        n_layers=d["L"],
-        d_model=d["D"],
-        n_heads=d["H"],
-        n_kv_heads=d["K"],
-        d_ff=d["F"],
-        vocab_size=d["V"],
-        head_dim=d["hd"],
-        rope_theta=float(config["rope_theta"]),
-        rms_eps=float(config["rms_norm_eps"]),
-        qk_norm=True,
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype="bfloat16",
-        param_dtype="bfloat16",
-    )
+from .weights import seed_key
 
 
 def serve_config(config: Dict[str, Any]) -> ServeConfig:
@@ -61,52 +41,13 @@ def serve_config(config: Dict[str, Any]) -> ServeConfig:
     )
 
 
-def to_program(w: Dict[str, Any]) -> Dict[str, Any]:
-    """The benchmark's weights in the program's parameter layout: layers
-    stacked (L, 1, ...), heads split out of the projections, and norm
-    weights stored as offsets from 1 (the program scales by 1 + w)."""
-    lw = w["layers"]
-    L, D, _ = lw["wq"].shape
-    hd = lw["q_norm"].shape[-1]
-
-    def off(g):  # exact in bf16: g is 1 + a multiple of 2^-7 near 1
-        return (g.astype(jnp.float32) - 1.0).astype(g.dtype)
-
-    def one(x):
-        return x[:, None]
-
-    p = {
-        "embed": {"tok": w["embed"]},
-        "final_norm": off(w["final_norm"]),
-        "decoder": {
-            "ln1": one(off(lw["attn_norm"])),
-            "ln2": one(off(lw["mlp_norm"])),
-            "attn": {
-                "wq": one(lw["wq"].reshape(L, D, -1, hd)),
-                "wk": one(lw["wk"].reshape(L, D, -1, hd)),
-                "wv": one(lw["wv"].reshape(L, D, -1, hd)),
-                "wo": one(lw["wo"].reshape(L, -1, hd, D)),
-                "q_norm": one(off(lw["q_norm"])),
-                "k_norm": one(off(lw["k_norm"])),
-            },
-            "mlp": {
-                "w_gate": one(lw["w_gate"]),
-                "w_up": one(lw["w_up"]),
-                "w_down": one(lw["w_down"]),
-            },
-        },
-    }
-    if "lm_head" in w:
-        p["lm_head"] = w["lm_head"]
-    return p
-
-
 def program_params(config: Dict[str, Any], seed: int, device) -> Any:
     """The program's parameters, made on `device` in one jitted call."""
-    cfg = model_config(config)
+    binding = arch.for_config(config)
+    cfg = binding.model_config(config)
     want = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
     make = jax.jit(
-        lambda key: to_program(make_weights(config, key)),
+        lambda key: binding.to_program(binding.make_weights(config, key), config),
         out_shardings=SingleDeviceSharding(device),
     )
     got = jax.eval_shape(make, seed_key(seed))
@@ -114,20 +55,23 @@ def program_params(config: Dict[str, Any], seed: int, device) -> Any:
         (a.shape, a.dtype) != (b.shape, b.dtype)
         for a, b in zip(jax.tree_util.tree_leaves(want), jax.tree_util.tree_leaves(got))
     ):
-        raise RuntimeError("the program's parameter layout differs from bench/lib/system.py")
+        raise RuntimeError(
+            f"the program's parameter layout differs from {binding.__file__}'s to_program"
+        )
     return make(seed_key(seed))
 
 
 def reference_weights(config: Dict[str, Any], seed: int, device) -> Any:
     """The same weights in the reference's layout, drawn anew from the seed."""
+    binding = arch.for_config(config)
     make = jax.jit(
-        lambda key: make_weights(config, key), out_shardings=SingleDeviceSharding(device)
+        lambda key: binding.make_weights(config, key), out_shardings=SingleDeviceSharding(device)
     )
     return make(seed_key(seed))
 
 
 def build_engine(config: Dict[str, Any], seed: int, device) -> ContinuousEngine:
-    cfg = model_config(config)
+    cfg = arch.for_config(config).model_config(config)
     return ContinuousEngine(cfg, program_params(config, seed, device), serve_config(config), device=device)
 
 

@@ -1,9 +1,11 @@
-"""The benchmark's float32 Qwen3 reference against the serving engine.
+"""Each architecture binding's float32 reference against the serving engine.
 
-At a tiny width on the CPU, the logits that `ContinuousEngine` computes
-while it prefills a prompt and then decodes through its bf16 cache, with a
-second request admitted into the running batch, agree with the
-reference's full forward pass over prompt + served tokens.
+At the binding's tiny size (`TINY`) on the CPU, the logits that
+`ContinuousEngine` computes while it prefills a prompt and then decodes
+through its bf16 cache, with a second request admitted into the running
+batch, agree with the reference's full forward pass over prompt + served
+tokens, within the binding's tolerance.  Every binding under `bench/arch`
+is tested; a new one needs nothing here.
 """
 
 from __future__ import annotations
@@ -13,15 +15,17 @@ import numpy as np
 import pytest
 import tinybench  # noqa: F401  (puts the repository root on sys.path)
 
-from bench.lib import reference, system, weights
+from bench import arch
+from bench.lib import system, weights
 
-# The engine holds activations, the KV cache and its matmul inputs in bf16
-# and rounds the head's output to bf16 before widening it (half an ulp is
-# 0.008 at a logit of 4).  Measured at this size: largest error 0.065 over
-# both requests and both head layouts.  0.15 leaves room for that and is
-# far below an error of the mechanism (a wrong position, mask, norm or
-# head gives errors of O(1) at logits of unit spread).
-ATOL = 0.15
+BINDINGS = arch.names()
+CASES = [(t, c) for t in BINDINGS for c in arch.binding(t).TINY["cases"]]
+
+
+def _tiny(model_type, case=None):
+    """tinybench's engine and limits with the binding's tiny model."""
+    tiny = arch.binding(model_type).TINY
+    return tinybench.config(**{**tiny["config"], **(tiny["cases"][case] if case else {})})
 
 
 def _serve_recording(cfg, seed, prompts, max_new, admit_after):
@@ -64,26 +68,45 @@ def _serve_recording(cfg, seed, prompts, max_new, admit_after):
     return {r: (finished[r].out, np.stack(logs[r][: max_new])) for r in ids}
 
 
-@pytest.mark.parametrize("tied", [False, True])
-def test_reference_matches_engine_prefill_then_decode(tied):
-    cfg = tinybench.config(tie_word_embeddings=tied)
+@pytest.mark.parametrize("model_type,case", CASES, ids=[f"{t}-{c}" for t, c in CASES])
+def test_reference_matches_engine_prefill_then_decode(model_type, case):
+    cfg = _tiny(model_type, case)
+    binding = arch.for_config(cfg)
+    vocab = int(cfg["vocab_size"])
     rng = np.random.default_rng(0)
-    prompts = {"a": rng.integers(0, 512, 21).tolist(), "b": rng.integers(0, 512, 35).tolist()}
+    prompts = {"a": rng.integers(0, vocab, 21).tolist(), "b": rng.integers(0, vocab, 35).tolist()}
     served = _serve_recording(cfg, seed=2**33 + 3, prompts=prompts, max_new=9, admit_after=3)
     w = system.reference_weights(cfg, 2**33 + 3, jax.devices()[0])
     for r, (out, got) in served.items():
         seq = prompts[r] + out[:-1]
         rows = np.arange(len(prompts[r]) - 1, len(seq))
-        ref = np.asarray(reference.logits_at(w, cfg, seq, rows))
+        ref = np.asarray(binding.logits_at(w, cfg, seq, rows))
         assert got.shape == ref.shape
         err = np.abs(got - ref).max()
-        assert err < ATOL, (r, err)
+        assert err < binding.TINY["atol"], (r, err, binding.TINY["why"])
         assert ref.std() > 0.5  # logits of unit spread: the tolerance means something
 
 
-def test_reference_weights_are_the_served_weights():
-    """The engine's parameters and the reference's come from one draw."""
-    cfg = tinybench.config()
+@pytest.mark.parametrize("model_type", BINDINGS)
+def test_reference_weights_are_the_served_weights(model_type):
+    """The engine's parameters and the reference's come from one draw:
+    every parameter is the binding's layout of the reference's weights."""
+    cfg = _tiny(model_type)
+    binding = arch.for_config(cfg)
+    dev = jax.devices()[0]
+    w = system.reference_weights(cfg, 77, dev)
+    p = system.program_params(cfg, 77, dev)
+    laid = jax.jit(lambda w: binding.to_program(w, cfg))(w)
+    assert jax.tree_util.tree_structure(p) == jax.tree_util.tree_structure(laid)
+    for a, b in zip(jax.tree_util.tree_leaves(p), jax.tree_util.tree_leaves(laid)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_qwen3_norms_are_served_as_offsets():
+    """Qwen3's program layout: heads split out of the projections, and
+    norm weights stored as offsets from 1, exactly."""
+    cfg = _tiny("qwen3")
     dev = jax.devices()[0]
     w = system.reference_weights(cfg, 77, dev)
     p = system.program_params(cfg, 77, dev)

@@ -6,6 +6,7 @@ from __future__ import annotations
 import pytest
 import tinybench
 
+from bench import arch
 from bench.lib import cost, readings, spec, trace
 from bench.lib.trace import Reduced
 
@@ -98,7 +99,7 @@ def test_joins_and_rooflines_stay_under_one():
     share = spec.metric_reader("decode_attention_roofline")(run)
     assert 99.0 < share <= 100.0
     prog, n = pre[0]
-    flash = cost.least_time(cost.flash_attention_cost(cfg, n), peaks)
+    flash = cost.least_time(arch.for_config(cfg).flash_attention_cost(cfg, n), peaks)
     assert flash * 1e9 < prog.kernels["flash_attention"]
     assert 0 < spec.metric_reader("step_mfu.chat")(run) < 100.0
 
@@ -126,8 +127,9 @@ def test_peaks_are_keyed_by_device_kind():
 
 def test_prefill_flops_count_the_head_once():
     cfg = tinybench.config()
-    p = cost.matmul_params(cfg)
+    qwen3 = arch.for_config(cfg)
+    p = qwen3.matmul_params(cfg)
     n = 10
-    assert cost.prefill_flops(cfg, n) == pytest.approx(
+    assert qwen3.prefill_flops(cfg, n) == pytest.approx(
         2 * p["layers"] * n + 2 * p["head"] + 4 * 4 * 16 * 2 * n * (n + 1) / 2
     )

@@ -12,6 +12,7 @@ if ROOT not in sys.path:
 
 CONFIG = {
     "name": "tiny-qwen3",
+    "model_type": "qwen3",
     "num_hidden_layers": 2,
     "hidden_size": 64,
     "num_attention_heads": 4,
