@@ -30,9 +30,17 @@ as read from `bench/configs/<name>.json`:
   decode_token_flops(config, ctx), prefill_flops(config, n)
       model FLOPs of one token decoded against `ctx` positions and of one
       prompt of `n` tokens (`step_mfu.chat` adds them up)
+  KERNELS
+      a tuple of the names of the Pallas kernels that this architecture's
+      programs run and whose device events the trace reader attributes,
+      summed per program (`Program.kernels`).  An op of another name is
+      read as any other op.  A program's kind (decode, prefill) comes from
+      its name, not from the kernels it holds, so a decode step that runs
+      none of them is still a decode step
   <kernel>_cost(config, ...) -> {"flops": ..., "bytes": ...}
-      the least operations and bytes of each kernel whose roofline share a
-      cell of this architecture reads (`bench/metrics/<kernel>_roofline.py`)
+      for each kernel in KERNELS (the loader refuses a binding without
+      one), the least operations and bytes of one call, for its roofline
+      share (`bench/metrics/<kernel>_roofline.py`)
   TINY
       {"config": model keys of a tiny CPU size, "cases": {name: overrides},
        "atol": tolerance of the engine against the reference there,
@@ -58,6 +66,7 @@ PROVIDES = (
     "logits_at",
     "decode_token_flops",
     "prefill_flops",
+    "KERNELS",
     "TINY",
 )
 
@@ -93,6 +102,7 @@ def binding(model_type: str, *, root: str = ROOT) -> ModuleType:
         del sys.modules[name]
         raise
     missing = [p for p in PROVIDES if not hasattr(mod, p)]
+    missing += [f"{k}_cost" for k in getattr(mod, "KERNELS", ()) if not hasattr(mod, f"{k}_cost")]
     if missing:
         del sys.modules[name]
         raise AttributeError(f"binding {os.path.relpath(path, root)} lacks {missing}")

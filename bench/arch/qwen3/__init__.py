@@ -13,6 +13,8 @@ from .layout import model_config, to_program
 from .reference import logits_at
 from .weights import make_weights
 
+KERNELS = ("decode_attention", "flash_attention")
+
 TINY = {
     "config": {
         "model_type": "qwen3",
@@ -42,6 +44,7 @@ TINY = {
 }
 
 __all__ = [
+    "KERNELS",
     "TINY",
     "decode_attention_cost",
     "decode_token_flops",

@@ -21,12 +21,14 @@ import tempfile
 import threading
 import time
 import traceback
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
+from bench import arch
 from repro.serve import request_plane as rp
 from repro.storage import KVStore, ObjectStore
 
@@ -300,20 +302,20 @@ def offer(
     if trace:
         from . import trace as trace_mod
 
-        run.trace = trace_mod.reduce_dir(trace_dir, spans)
+        run.trace = trace_mod.reduce_dir(trace_dir, spans, arch.for_config(cell.config).KERNELS)
         shutil.rmtree(trace_dir, ignore_errors=True)
         if run.trace is not None:
             from . import readings
 
-            kinds: Dict[str, int] = {}
-            for p in run.trace.programs:
-                kinds[p.kind] = kinds.get(p.kind, 0) + 1
+            kinds = Counter(p.kind for p in run.trace.programs)
+            names = Counter(p.name for p in run.trace.programs)
             log(
                 f"trace: window_s={run.trace.window_s} busy_s={run.trace.busy_s} "
                 f"ops={len(run.trace.ops)} modules={len(run.trace.modules)} "
                 f"host_spans={len(run.trace.host)} "
                 f"joined={sum(i is not None for i in run.trace.host_info)} "
-                f"programs={kinds} prefills_matched={len(readings.prefills(run.trace))} "
+                f"programs={dict(kinds)} program_names={dict(names)} "
+                f"prefills_matched={len(readings.prefills(run.trace))} "
                 f"chunks_matched={len(readings.decode_chunks(run.trace))}"
             )
         else:

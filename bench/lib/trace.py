@@ -7,9 +7,11 @@ trace's one clock.  `Reduced` does the arithmetic on those intervals, so
 a test can feed it a synthetic trace:
 
   busy        union of device-op intervals inside the window
-  programs    each program execution is a decode step if a
-              `decode_attention` kernel ran inside it, a prefill if a
-              `flash_attention` one did, else "other"
+  programs    each program execution is a decode step, a prefill or
+              "other" by the name the engine gave its program
+              (`program_kind`); the device time of each kernel that the
+              cell's architecture binding names (its `KERNELS`) and that
+              ran inside it is summed per kernel
   gaps        device-idle intervals, split over the host spans that
               overlap them (the rest is "outside_spans")
 """
@@ -20,11 +22,13 @@ import glob
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[str, int, int]  # (name, start_ns, end_ns)
 
-KERNELS = ("decode_attention", "flash_attention")
+# the engine's jitted functions are named so that a profile tells them
+# apart (`serve/continuous.py`); every other program is "other"
+PROGRAM_KINDS = {"jit_decode": "decode", "jit_prefill": "prefill"}
 
 
 def op_name(event_name: str) -> str:
@@ -34,13 +38,21 @@ def op_name(event_name: str) -> str:
     return m.group(1) if m else event_name
 
 
-def kernel_of(name: str) -> Optional[str]:
-    """The Pallas kernel an op belongs to, by its instruction name
-    (`decode_attention`, `decode_attention.3`, ...)."""
-    for k in KERNELS:
-        if re.fullmatch(rf"{k}(\.\d+)?", name):
+def kernel_of(name: str, kernels: Sequence[str]) -> Optional[str]:
+    """The kernel of `kernels` that an op belongs to, by its instruction
+    name (`decode_attention`, `decode_attention.3`, ...)."""
+    for k in kernels:
+        if re.fullmatch(rf"{re.escape(k)}(\.\d+)?", name):
             return k
     return None
+
+
+def program_kind(module_name: str) -> str:
+    """decode, prefill or other, by the name of an "XLA Modules" event:
+    the jitted function's name, with the suffixes a trace may add
+    (`jit_decode(1234)`, `jit_decode.1`)."""
+    m = re.fullmatch(r"(\w+)(?:\(\d+\)|\.\d+)*", module_name)
+    return PROGRAM_KINDS.get(m.group(1), "other") if m else "other"
 
 
 def union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -61,6 +73,7 @@ def clip(ivs: List[Tuple[int, int]], lo: int, hi: int) -> List[Tuple[int, int]]:
 
 @dataclass
 class Program:
+    name: str  # the "XLA Modules" event's name
     kind: str  # decode | prefill | other
     start: int
     end: int
@@ -73,6 +86,7 @@ class Reduced:
     ops: List[Interval]
     modules: List[Interval]
     host: List[Interval]  # (span name, start, end), host spans in the window
+    kernels: Sequence[str]  # the kernels summed per program (a binding's KERNELS)
     host_info: List[Any] = field(default_factory=list)  # per host span, its record's info
 
     def __post_init__(self) -> None:
@@ -107,10 +121,10 @@ class Reduced:
 
     def _programs(self) -> List[Program]:
         lo, hi = self.window
-        mods = sorted((s, e) for _, s, e in self.modules if s >= lo and e <= hi)
-        kern = sorted((s, e, k) for n, s, e in self.ops if (k := kernel_of(n)))
+        mods = sorted((s, e, n) for n, s, e in self.modules if s >= lo and e <= hi)
+        kern = sorted((s, e, k) for n, s, e in self.ops if (k := kernel_of(n, self.kernels)))
         progs, j = [], 0
-        for s, e in mods:
+        for s, e, name in mods:
             while j < len(kern) and kern[j][0] < s:
                 j += 1
             sums: Dict[str, int] = {}
@@ -119,12 +133,7 @@ class Reduced:
                 ks, ke, k = kern[i]
                 sums[k] = sums.get(k, 0) + (ke - ks)
                 i += 1
-            kind = (
-                "decode" if "decode_attention" in sums
-                else "prefill" if "flash_attention" in sums
-                else "other"
-            )
-            progs.append(Program(kind, s, e, sums))
+            progs.append(Program(name, program_kind(name), s, e, sums))
         return progs
 
     def of_kind(self, kind: str) -> List[Program]:
@@ -211,8 +220,9 @@ def load(path: str, *, host_prefix: str = "bench.") -> Dict[str, Any]:
     return {"ops": ops, "modules": modules, "host": host, "device": device_seen}
 
 
-def reduce_dir(logdir: str, spans) -> Optional[Reduced]:
-    """The trace under `logdir` reduced over the traced window.  Host span
+def reduce_dir(logdir: str, spans, kernels: Sequence[str]) -> Optional[Reduced]:
+    """The trace under `logdir` reduced over the traced window, with the
+    device time of each of `kernels` summed per program.  Host span
     events carry the index of their record in `spans` (`idx`), which joins
     each to what the benchmark recorded of it (tokens, lengths)."""
     files = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
@@ -236,5 +246,6 @@ def reduce_dir(logdir: str, spans) -> Optional[Reduced]:
         ops=raw["ops"],
         modules=raw["modules"],
         host=[(n, s, e) for n, s, e, _ in host],
+        kernels=kernels,
         host_info=info,
     )

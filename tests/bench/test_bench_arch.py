@@ -17,6 +17,9 @@ import tinybench
 
 from bench import arch
 from bench.lib import spec
+from bench.lib.trace import Reduced
+
+MS = 1_000_000  # ns
 
 
 def test_unknown_model_type_names_the_file_it_wanted():
@@ -32,7 +35,9 @@ def test_every_binding_provides_the_interface():
     assert "qwen3" in arch.names()
     for t in arch.names():
         b = arch.binding(t)
-        assert all(callable(getattr(b, p)) for p in arch.PROVIDES if p != "TINY")
+        assert all(callable(getattr(b, p)) for p in arch.PROVIDES if p not in ("TINY", "KERNELS"))
+        assert isinstance(b.KERNELS, tuple) and b.KERNELS
+        assert all(callable(getattr(b, f"{k}_cost")) for k in b.KERNELS)
         assert {"config", "cases", "atol", "why"} <= set(b.TINY)
         assert b.TINY["config"]["model_type"] == t
 
@@ -91,3 +96,43 @@ def test_a_new_architecture_is_new_files_only(tmp_path):
     with pytest.raises(FileNotFoundError, match="bench/arch/qwen3"):
         arch.for_config(tinybench.config(), root=root)  # only its own root's bindings
     assert _tree(tinybench.ROOT) == before
+
+
+def _stub(root, model_type, body):
+    """A binding under `root` that re-exports Qwen3 and adds `body`."""
+    os.makedirs(os.path.join(root, "bench/arch"), exist_ok=True)
+    with open(os.path.join(root, f"bench/arch/{model_type}.py"), "w") as f:
+        f.write("from bench.arch.qwen3 import *  # noqa: F401,F403\n\n" + body)
+    return arch.binding(model_type, root=str(root))
+
+
+def test_the_trace_reader_attributes_the_bindings_own_kernels(tmp_path):
+    """A binding that names a kernel of its own has that kernel's events
+    summed per program, and a kernel it does not name is an ordinary op."""
+    b = _stub(tmp_path, "mla_stub", (
+        'KERNELS = ("mla_decode",)\n\n\n'
+        "def mla_decode_cost(config, ctxs):\n"
+        '    return {"flops": 1.0, "bytes": 1.0}\n'
+    ))
+    assert b.KERNELS == ("mla_decode",)
+    ops = [
+        ("mla_decode", 1 * MS, 2 * MS),
+        ("mla_decode.4", 2 * MS, 4 * MS),
+        ("decode_attention", 4 * MS, 5 * MS),
+        ("fusion.2", 6 * MS, 7 * MS),
+    ]
+    modules = [("jit_decode", 0, 5 * MS), ("jit_prefill", 6 * MS, 8 * MS)]
+    red = Reduced(window=(0, 10 * MS), ops=ops, modules=modules, host=[], kernels=b.KERNELS)
+    assert [p.kind for p in red.programs] == ["decode", "prefill"]
+    assert red.programs[0].kernels == {"mla_decode": 3 * MS}
+    assert red.programs[1].kernels == {}
+    device_ops = dict(map(tuple, red.breakdown()["device_ops"]))
+    assert device_ops["decode:mla_decode"] == pytest.approx(3e-3)
+    assert device_ops["decode:decode_attention"] == pytest.approx(1e-3)
+
+
+def test_a_binding_that_names_a_kernel_without_its_cost_is_refused(tmp_path):
+    with pytest.raises(AttributeError, match=r"\['mla_decode_cost'\]"):
+        _stub(tmp_path, "no_cost", 'KERNELS = ("decode_attention", "mla_decode")\n')
+    with pytest.raises(AttributeError, match="mla_decode_cost"):  # not kept half-loaded
+        arch.binding("no_cost", root=str(tmp_path))

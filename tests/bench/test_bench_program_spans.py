@@ -9,6 +9,7 @@ from types import SimpleNamespace
 import pytest
 import tinybench
 
+from bench import arch
 from bench.lib import program_spans, spec
 from bench.lib.trace import Reduced
 from repro.serve.tracing import SpanRecord
@@ -45,7 +46,8 @@ def _run(stats=None, traced=True, outlier=True, pairs=4):
         records.append(("step_chunk", s - OFF - shift, e - OFF - shift, obj, k))
     host.append(("lease_requests", 25 * MS, 26 * MS))
     info.append(None)  # a span with no record joins nothing
-    red = Reduced(window=(0, 30 * MS), ops=ops, modules=modules, host=host, host_info=info)
+    red = Reduced(window=(0, 30 * MS), ops=ops, modules=modules, host=host,
+                  kernels=arch.binding("qwen3").KERNELS, host_info=info)
     return SimpleNamespace(
         trace=red if traced else None,
         spans=SimpleNamespace(records=records),

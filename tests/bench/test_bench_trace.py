@@ -11,6 +11,7 @@ from bench.lib import cost, readings, spec, trace
 from bench.lib.trace import Reduced
 
 MS = 1_000_000  # ns
+KERNELS = arch.binding("qwen3").KERNELS
 
 
 def _trace():
@@ -28,10 +29,10 @@ def _trace():
         ("fusion.9", 24 * MS, 26 * MS),
     ]
     modules = [
-        ("jit__lambda", 0, 5 * MS),
+        ("jit_decode", 0, 5 * MS),
         ("jit_argmax", 6 * MS, 7 * MS),
-        ("jit__lambda", 10 * MS, 12 * MS),
-        ("jit__lambda", 20 * MS, 26 * MS),
+        ("jit_decode", 10 * MS, 12 * MS),
+        ("jit_prefill", 20 * MS, 26 * MS),
     ]
     host = [
         ("step_chunk", 0, 13 * MS),
@@ -43,7 +44,8 @@ def _trace():
         None,
         [("r00001", 37)],
     ]
-    return Reduced(window=(0, 30 * MS), ops=ops, modules=modules, host=host, host_info=info)
+    return Reduced(window=(0, 30 * MS), ops=ops, modules=modules, host=host, kernels=KERNELS,
+                   host_info=info)
 
 
 def test_idle_share_is_one_minus_union_of_op_intervals():
@@ -54,13 +56,53 @@ def test_idle_share_is_one_minus_union_of_op_intervals():
     assert red.idle_share() == pytest.approx(1 - 14 / 30)
 
 
-def test_programs_split_by_the_kernel_they_hold():
+def test_programs_split_by_the_engines_program_name():
     red = _trace()
     assert [p.kind for p in red.programs] == ["decode", "other", "decode", "prefill"]
     assert red.programs[0].kernels == {"decode_attention": 2 * MS}
     assert red.programs[3].kernels == {"flash_attention": 4 * MS}
     # idle between the two decode programs: 5 ms gap less 1 ms of argmax
     assert red.decode_gaps_ns() == [4 * MS]
+
+
+@pytest.mark.parametrize("name, kind", [
+    ("jit_decode", "decode"),
+    ("jit_decode(8317062183547810941)", "decode"),
+    ("jit_decode.3", "decode"),
+    ("jit_prefill", "prefill"),
+    ("jit_prefill(42)", "prefill"),
+    ("jit_decode_foo", "other"),
+    ("jit_insert", "other"),
+    ("jit_new_cache(7)", "other"),
+    ("jit__lambda", "other"),
+    ("decode", "other"),
+])
+def test_program_kind_is_the_engines_program_name(name, kind):
+    assert trace.program_kind(name) == kind
+
+
+def test_a_decode_program_with_no_kernel_is_still_a_decode_step():
+    """A decode step written in plain ops (no kernel the binding names)
+    is read as decode by the step time, the gaps and the joins."""
+    ops = [
+        ("fusion.1", 0, 4 * MS),
+        ("argmax", 6 * MS, 7 * MS),
+        ("fusion.1", 10 * MS, 13 * MS),
+    ]
+    modules = [("jit_decode", 0, 5 * MS), ("jit_argmax", 6 * MS, 7 * MS),
+               ("jit_decode", 10 * MS, 13 * MS)]
+    host = [("step_chunk", 0, 14 * MS)]
+    info = [{"steps": 2, "ctx": [100, 101]}]
+    red = Reduced(window=(0, 20 * MS), ops=ops, modules=modules, host=host, kernels=KERNELS,
+                  host_info=info)
+    assert [p.kind for p in red.programs] == ["decode", "other", "decode"]
+    assert all(p.kernels == {} for p in red.programs)
+    run = type("Run", (), {"trace": red})()
+    assert spec.metric_reader("decode_step_ms")(run) == pytest.approx(4.0)
+    # 5 ms between the two decode programs, 1 ms of it busy with argmax
+    assert spec.metric_reader("host_gap_ms.chat")(run) == pytest.approx(4.0)
+    chunks = readings.decode_chunks(red)
+    assert [(len(progs), ctx) for progs, ctx in chunks] == [(2, [100, 101])]
 
 
 def test_idle_gaps_attributed_to_host_spans():
@@ -112,10 +154,10 @@ def test_no_trace_gives_no_reading():
 
 
 def test_kernel_names():
-    assert trace.kernel_of("decode_attention") == "decode_attention"
-    assert trace.kernel_of("flash_attention.12") == "flash_attention"
-    assert trace.kernel_of("fusion.3") is None
-    assert trace.kernel_of("decode_attention_bwd") is None
+    assert trace.kernel_of("decode_attention", KERNELS) == "decode_attention"
+    assert trace.kernel_of("flash_attention.12", KERNELS) == "flash_attention"
+    assert trace.kernel_of("fusion.3", KERNELS) is None
+    assert trace.kernel_of("decode_attention_bwd", KERNELS) is None
 
 
 def test_peaks_are_keyed_by_device_kind():
