@@ -125,6 +125,7 @@ def main() -> None:
         store, kv, engine_id=args.engine_id, idle_timeout_s=args.idle_timeout
     )
     dt = time.time() - t0
+    kv_share = stats["decode_kv_positions_read"] / max(stats["decode_kv_positions_held"], 1)
     print(
         f"{args.engine_id}: served {stats['served']} requests, "
         f"{stats['tokens_out']} tokens in {dt:.1f}s "
@@ -134,7 +135,8 @@ def main() -> None:
         f"mean lease wait {_mean_ms(stats, 'lease_wait_ns', 'leased'):.2f} ms, "
         f"first-token hold "
         f"{_mean_ms(stats, 'first_token_hold_ns', 'first_tokens_streamed'):.2f} ms, "
-        f"host {_mean_ms(stats, 'decode_host_ns', 'decode_steps'):.3f} ms per decode step)",
+        f"host {_mean_ms(stats, 'decode_host_ns', 'decode_steps'):.3f} ms per decode step; "
+        f"{100 * kv_share:.1f}% of the held cache attended to)",
         flush=True,
     )
 

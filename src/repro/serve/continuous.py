@@ -30,7 +30,10 @@ requests leased and their summed wait from submit to lease
 (`lease_wait_ns`), the first tokens streamed and their summed hold from
 sampling to the return of the push that carried them
 (`first_token_hold_ns`), and the engine thread's time per decode step
-outside the token read-back (`decode_host_ns`, over `decode_steps`).
+outside the token read-back (`decode_host_ns`, over `decode_steps`), and
+the cache positions the decode steps attend to (`decode_kv_positions_read`,
+each live slot's length with the token fed) against those they hold
+reserved (`decode_kv_positions_held`, `max_batch` x `max_len` a step).
 While a profiler session is on, the loop also opens `serve.*` spans
 (`serve.tracing`); docs/ARCHITECTURE.md lists them and how they nest.
 
@@ -142,6 +145,8 @@ class ContinuousEngine:
             "first_tokens_streamed": 0,
             "first_token_hold_ns": 0,
             "decode_host_ns": 0,
+            "decode_kv_positions_read": 0,
+            "decode_kv_positions_held": 0,
         }
 
     def _span(self, name: str, **kw):
@@ -298,6 +303,9 @@ class ContinuousEngine:
                 self._put(self.cache_lens),
             )
             self.stats["decode_steps"] += 1
+            # a free slot's cache_len is 0, so the sum runs over the live ones
+            self.stats["decode_kv_positions_read"] += int(self.cache_lens.sum()) + len(live)
+            self.stats["decode_kv_positions_held"] += scfg.max_batch * scfg.max_len
             keys = self._put(self.keys) if scfg.temperature > 0 else None
             sampled = sample_tokens(logits[:, 0], keys, self.steps, scfg.temperature)
             t_rb = time.perf_counter_ns()

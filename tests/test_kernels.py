@@ -14,7 +14,7 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.kernels import ops, ref
-from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.decode_attention import decode_attention_pallas, live_block
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba2_ssd import ssd_pallas
 from repro.kernels.mlstm_kernel import mlstm_pallas
@@ -118,15 +118,24 @@ def test_flash_attention_grad_finite():
     (512, 4, 4, 32, None, None),
     (256, 8, 1, 128, 64, None),
     (256, 4, 2, 64, None, 30.0),
+    (512, 16, 8, 128, None, None),   # qwen3-1.7b grouping
+    (512, 64, 8, 128, None, None),   # qwen3-32b grouping
+    (640, 16, 8, 128, 300, None),    # window: the full slot's first live block is 2
+    (640, 16, 8, 128, 300, 50.0),    # ... under a softcap (gemma2)
+    (256, 14, 2, 64, None, None),    # internvl2-1b grouping (7 q heads a KV head)
+    (256, 20, 20, 64, None, None),   # whisper-large-v3 decoder (MHA, K 20)
 ])
 def test_decode_attention_pallas_vs_ref(S, H, K, D, window, cap):
-    B = 3
+    block_k = 128
+    # full, half, short, a free slot (cache_len 1 in decode), one whole
+    # block, one position into the second block
+    clen = jnp.asarray([S, S // 2, 17, 1, block_k, block_k + 1], jnp.int32)
+    B = clen.shape[0]
     rng = np.random.default_rng(S + H)
     q = _rand(rng, (B, H, D))
     kc = _rand(rng, (B, S, K, D))
     vc = _rand(rng, (B, S, K, D))
-    clen = jnp.asarray([S, S // 2, 17], jnp.int32)
-    out = decode_attention_pallas(q, kc, vc, clen, window=window, logit_cap=cap, block_k=128)
+    out = decode_attention_pallas(q, kc, vc, clen, window=window, logit_cap=cap, block_k=block_k)
     exp = ref.decode_attention_reference(q, kc, vc, clen, window=window, logit_cap=cap)
     np.testing.assert_allclose(out, exp, atol=2e-5, rtol=1e-4)
 
@@ -148,6 +157,26 @@ def test_decode_attention_pallas_partial_block(S, block_k):
     out = decode_attention_pallas(q, kc, vc, clen, block_k=block_k)
     exp = ref.decode_attention_reference(q, kc, vc, clen)
     np.testing.assert_allclose(out, exp, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 1, 3, 4, 5, 9, 40])
+def test_decode_live_block_clamp(window):
+    """The K/V index maps read exactly the live blocks of a slot, and every
+    dead grid step repeats a neighbour's block index, so the pipeline
+    fetches each live block once and nothing else."""
+    block_k, n_k = 4, 6
+    j = jnp.arange(n_k)
+    for clen in range(0, block_k * n_k + 1):
+        idx = [int(b) for b in live_block(j, jnp.int32(clen), window, block_k)]
+        lo = max(0, clen - window) if window else 0
+        live = [b for b in range(n_k) if b * block_k < clen and (b + 1) * block_k > lo]
+        assert sorted(set(idx)) == (live or [0]), (clen, idx)
+        for b in range(n_k):
+            if b not in live:
+                near = [idx[n] for n in (b - 1, b + 1) if 0 <= n < n_k]
+                assert idx[b] in near, (clen, b, idx)
+        fetches = 1 + sum(idx[b] != idx[b - 1] for b in range(1, n_k))
+        assert fetches == max(len(live), 1), (clen, idx)
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,21 @@ def test_counters_count_every_request(engine):
     assert len(tracer.records()) == before
 
 
+def test_kv_position_counters(engine):
+    """Each decode step adds each live slot's attended length (its cache
+    length with the fed token) and the whole reserved cache."""
+    for k in engine.stats:
+        engine.stats[k] = 0
+    engine.admit([("kv-a", list(range(1, 6)), 5), ("kv-b", list(range(1, 10)), 5)])
+    engine.step_chunk(2)
+    assert engine.stats["decode_steps"] == 2
+    assert engine.stats["decode_kv_positions_read"] == (5 + 1) + (9 + 1) + (6 + 1) + (10 + 1)
+    scfg = engine.scfg
+    assert engine.stats["decode_kv_positions_held"] == 2 * scfg.max_batch * scfg.max_len
+    while engine.n_live():
+        engine.step_chunk()
+
+
 def test_spans_nest_and_carry_request_ids(engine, tmp_path):
     before = len(tracer.records())
     jax.profiler.start_trace(str(tmp_path))
